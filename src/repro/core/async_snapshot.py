@@ -94,13 +94,12 @@ _BACKEND: Optional[str] = None
 
 
 def _backend() -> str:
+    """The platform JAX runs on; a failed probe raises rather than
+    passing for "cpu" and hiding the device."""
     global _BACKEND
     if _BACKEND is None:
-        try:
-            import jax
-            _BACKEND = jax.default_backend()
-        except Exception:  # pragma: no cover
-            _BACKEND = "cpu"
+        import jax
+        _BACKEND = jax.default_backend()
     return _BACKEND
 
 
@@ -185,13 +184,15 @@ class _SparseCtx:
         return "host", fp, (weakref.ref(v) if is_jax else None)
 
     def record(self, name: str, path: str, v,
-               host_bytes: Optional[np.ndarray] = None) -> None:
-        """Refresh the fingerprint baseline after a dense capture."""
+               host_bytes: Optional[np.ndarray] = None) -> str:
+        """Refresh the fingerprint baseline after a dense capture;
+        returns where it was computed ("tpu" kernel or "host")."""
         impl, fp, wref = self._fingerprint(v, host_bytes)
         self.fp[(name, path)] = _LeafFP(
             impl=impl, chunk_bytes=self.chunk_bytes,
             shape=tuple(v.shape), dtype=str(v.dtype), nbytes=v.nbytes,
             fp=fp, wref=wref)
+        return impl
 
     def prune(self) -> None:
         """Drop baselines for leaves absent from this capture, so a leaf
@@ -253,10 +254,12 @@ class _StagingSlot:
         falls through to the dense copy and refreshes its fingerprint
         baseline for the next capture."""
         import jax
-        accel = jax.default_backend() != "cpu"
+        accel = _backend() != "cpu"
         out: Dict[str, Dict[str, Any]] = {}
         st = {"capture_bytes": 0, "dirty_chunks": 0, "clean_chunks": 0,
-              "identity_skips": 0, "sparse_leaves": 0}
+              "identity_skips": 0, "sparse_leaves": 0,
+              "fp_kernel_leaves": 0, "fused_leaves": 0,
+              "fused_overflows": 0, "host_fp_leaves": 0}
         for name, e in upper.items():
             pool = self.buffers.setdefault(name, {})
             taken: Dict[str, Any] = {}
@@ -287,9 +290,11 @@ class _StagingSlot:
                     # fingerprint the *staged* copy: for an in-place-
                     # mutated numpy leaf only the staged bytes are
                     # guaranteed to be this snapshot's
-                    ctx.record(name, path, v,
-                               host_bytes=np.ascontiguousarray(a)
-                               .reshape(-1).view(np.uint8))
+                    impl = ctx.record(name, path, v,
+                                      host_bytes=np.ascontiguousarray(a)
+                                      .reshape(-1).view(np.uint8))
+                    st["fp_kernel_leaves" if impl == "tpu"
+                       else "host_fp_leaves"] += 1
             out[name] = taken
         if ctx is not None:
             ctx.prune()
@@ -325,9 +330,13 @@ class _StagingSlot:
             fp_new, idx, compact = ops.fused_dirty_chunk_capture(
                 v, fpe.fp, cb, capacity_hint=fpe.last_dirty)
             wref = weakref.ref(v)
+            st["fused_leaves"] += 1
+            if idx.size > ops.fused_capacity(n_chunks, cb, fpe.last_dirty):
+                st["fused_overflows"] += 1   # finished by the gather path
         elif fpe.impl == "host":
             buf = _leaf_bytes(v)
             fp_new = _fp_host_threaded(buf, cb, ctx.pool, ctx.workers)
+            st["host_fp_leaves"] += 1
             idx = np.nonzero(np.any(fp_new != fpe.fp, axis=1))[0]
             compact = None
             if idx.size:
@@ -489,6 +498,12 @@ class AsyncSnapshotter:
             "bytes_written": 0, "bytes_logical": 0, "bytes_encoded": 0,
             "capture_bytes": 0, "sparse_leaves": 0, "identity_skips": 0,
             "dirty_chunks": 0, "clean_chunks": 0,
+            # where dirty detection ran, per eligible leaf capture: the
+            # fingerprint kernel (baseline), the fused capture kernel
+            # (chain link; an overflow is finished by the gather path),
+            # or the host fingerprint (either)
+            "fp_kernel_leaves": 0, "fused_leaves": 0, "fused_overflows": 0,
+            "host_fp_leaves": 0,
             "save_seconds": 0.0, "capture_seconds": 0.0,
             "encode_commit_seconds": 0.0,
         }
@@ -644,6 +659,11 @@ class AsyncSnapshotter:
                         raise SnapshotError(
                             f"sparse capture of {name}:{path} has no "
                             "previous value in the pinned mirror")
+                    if not prev_arr.flags.writeable:
+                        # staged straight from device_get (read-only);
+                        # the mirror is patched in place from here on
+                        prev_arr = base_state[name][path] = \
+                            np.array(prev_arr)
                     m = deltamod.encode_leaf_sparse(
                         arr.shape, arr.dtype, arr.chunk_bytes,
                         arr.n_chunks, arr.dirty_idx,
@@ -669,7 +689,8 @@ class AsyncSnapshotter:
                         mirror = base_state.setdefault(name, {})
                         old = mirror.get(path)
                         if old is not None and old.shape == arr.shape \
-                                and old.dtype == arr.dtype:
+                                and old.dtype == arr.dtype \
+                                and old.flags.writeable:
                             np.copyto(old, arr)
                         else:
                             mirror[path] = np.array(arr)

@@ -164,16 +164,13 @@ _device_xor: Optional[bool] = None
 
 
 def _use_device_xor() -> bool:
-    """XOR through the Pallas kernel when an accelerator is attached
-    (kernels/ckpt_codec); the host path stays pure numpy so the encode
-    thread never initializes jax on CPU-only deployments."""
+    """XOR through the Pallas kernel when a TPU is attached
+    (kernels/ckpt_codec), numpy otherwise. A failed backend probe
+    raises: it must not pass for "no device"."""
     global _device_xor
     if _device_xor is None:
-        try:
-            import jax
-            _device_xor = jax.default_backend() == "tpu"
-        except Exception:  # pragma: no cover
-            _device_xor = False
+        import jax
+        _device_xor = jax.default_backend() == "tpu"
     return _device_xor
 
 

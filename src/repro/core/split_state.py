@@ -30,6 +30,7 @@ from repro.core.oplog import (
     DataReassign, ScheduleSet,
 )
 from repro.core.virtual_ids import HandleTable, DeviceMap, VirtualId
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -282,7 +283,7 @@ class LowerHalf:
             if self.mesh_factory is not None:
                 mesh = self.mesh_factory()
             else:
-                mesh = jax.make_mesh(tuple(op.shape), tuple(op.axes))
+                mesh = make_mesh(op.shape, op.axes)
             self.devices.bind_mesh(mesh)
             self.handles.bind(op.vmesh, mesh)
             self.vmesh = op.vmesh

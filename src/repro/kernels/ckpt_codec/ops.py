@@ -1,5 +1,5 @@
-"""jit'd wrappers for the checkpoint codec kernel (padding, device
-dispatch, interpret fallback on CPU)."""
+"""jit'd wrappers for the checkpoint codec kernels (padding, layout,
+device dispatch; interpret mode off the TPU, for tests)."""
 from __future__ import annotations
 
 import functools
@@ -25,10 +25,6 @@ def _default_interpret() -> bool:
     return _INTERPRET_DEFAULT
 
 
-def _on_tpu() -> bool:
-    return not _default_interpret()
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def quantize(x: jax.Array, *, interpret: bool = None):
     """x: f32 any shape -> (q [nb, BLOCK] int8, scale [nb] f32)."""
@@ -44,7 +40,7 @@ def quantize(x: jax.Array, *, interpret: bool = None):
     if rpad:
         xb = jnp.pad(xb, ((0, rpad), (0, 0)))
     q, s = K.quantize_blocks(xb, interpret=interpret)
-    return q[:nb], s[:nb]
+    return q[:nb], s[:nb, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -98,28 +94,24 @@ def _fingerprint_impl(x: jax.Array, *, chunk_bytes: int,
                       interpret: bool = None):
     if interpret is None:
         interpret = _default_interpret()
-    xi = _device_i32_chunks(x, chunk_bytes)
-    rows = chunk_bytes // (4 * BLOCK)
-    return K.fingerprint_blocks(xi.reshape(-1, BLOCK), rows,
-                                interpret=interpret)
+    fp = K.fingerprint_blocks(_device_chunks(x, chunk_bytes),
+                              interpret=interpret)
+    return fp[:, 0, :2]
 
 
-def _device_i32_chunks(x: jax.Array, chunk_bytes: int) -> jax.Array:
-    """Reinterpret a device array as i32 [n_chunks, chunk_elems] without
-    leaving the device (zero-padded to a chunk multiple)."""
+def _device_chunks(x: jax.Array, chunk_bytes: int) -> jax.Array:
+    """View a device array as [n_chunks, rows, BLOCK] of its own dtype
+    (zero-padded to a chunk multiple) without leaving the device. The
+    kernels read the raw bits themselves: a bitcast here would be a
+    separate pass over the leaf, and regrouping narrow elements into i32
+    words would give a [n, k] array whose minor dim the TPU pads to a
+    full 128-lane tile."""
     flat = x.reshape(-1)
-    if flat.dtype.itemsize != 4:
-        b = jax.lax.bitcast_convert_type(flat, jnp.uint8).reshape(-1)
-        pad = (-b.size) % chunk_bytes
-        if pad:
-            b = jnp.pad(b, (0, pad))
-        xi = jax.lax.bitcast_convert_type(b.reshape(-1, 4), jnp.int32)
-    else:
-        xi = jax.lax.bitcast_convert_type(flat, jnp.int32)
-        pad = (-xi.size) % (chunk_bytes // 4)
-        if pad:
-            xi = jnp.pad(xi, (0, pad))
-    return xi.reshape(-1, chunk_bytes // 4)
+    size = flat.dtype.itemsize
+    pad = (-flat.size) % (chunk_bytes // size)
+    if pad:
+        flat = jnp.pad(flat, (0, pad))
+    return flat.reshape(-1, chunk_bytes // (size * BLOCK), BLOCK)
 
 
 def chunk_fingerprints(x, chunk_bytes: int = FP_CHUNK_BYTES, *,
@@ -140,8 +132,7 @@ def _dirty_mask(fp: jax.Array, prev_fp: jax.Array) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("chunk_bytes",))
 def _gather_chunks(x: jax.Array, idx: jax.Array, *, chunk_bytes: int):
-    xi = _device_i32_chunks(x, chunk_bytes)
-    return jnp.take(xi, idx, axis=0)
+    return jnp.take(_device_chunks(x, chunk_bytes), idx, axis=0)
 
 
 def dirty_chunk_capture(x, prev_fp, chunk_bytes: int = FP_CHUNK_BYTES, *,
@@ -211,10 +202,12 @@ def _fused_capture_impl(x: jax.Array, prev_fp: jax.Array, *,
                         interpret: bool = None):
     if interpret is None:
         interpret = _default_interpret()
-    xi = _device_i32_chunks(x, chunk_bytes)
-    rows = chunk_bytes // (4 * BLOCK)
-    return K.fused_capture_blocks(xi.reshape(-1, BLOCK), prev_fp, rows,
-                                  capacity, interpret=interpret)
+    # previous fingerprints enter in the kernel's lane-dense row layout
+    pfp = jnp.pad(prev_fp, ((0, 0), (0, K.FP_LANES - 2)))[:, None, :]
+    fp, cnt, idx, compact = K.fused_capture_blocks(
+        _device_chunks(x, chunk_bytes), pfp, capacity,
+        interpret=interpret)
+    return fp[:, 0, :2], cnt, idx, compact
 
 
 def fused_dirty_chunk_capture(
@@ -249,7 +242,7 @@ def fused_dirty_chunk_capture(
         interpret=interpret)
     # the one blocking hop: count + indices + compacted payload together
     cnt_h, idx_h, compact_h = jax.device_get((cnt, idx, compact))
-    k = int(cnt_h[0, 0])
+    k = int(cnt_h[0])
     if k == 0:
         return fp, np.empty(0, np.int64), None
     if k > capacity:
@@ -267,9 +260,8 @@ def fused_dirty_chunk_capture(
         host = np.asarray(jax.device_get(gathered))[:full_idx.size]
         return (fp, full_idx.astype(np.int64),
                 host.view(np.uint8).reshape(full_idx.size, chunk_bytes))
-    rows = chunk_bytes // (4 * BLOCK)
-    dirty_idx = idx_h[:k, 0].astype(np.int64)
-    dirty_bytes = np.ascontiguousarray(compact_h[:k * rows]) \
+    dirty_idx = idx_h[:k].astype(np.int64)
+    dirty_bytes = np.ascontiguousarray(compact_h[:k]) \
         .view(np.uint8).reshape(k, chunk_bytes)
     return fp, dirty_idx, dirty_bytes
 
@@ -284,5 +276,5 @@ def dequantize(q: jax.Array, scale: jax.Array, *, interpret: bool = None):
     if rpad:
         q = jnp.pad(q, ((0, rpad), (0, 0)))
         scale = jnp.pad(scale, (0, rpad))
-    x = K.dequantize_blocks(q, scale, interpret=interpret)
+    x = K.dequantize_blocks(q, scale[:, None], interpret=interpret)
     return x[:nb].reshape(-1)

@@ -154,9 +154,8 @@ def flash_attention_bwd(
     def cp(sem):
         if interpret:
             return {}
-        c = getattr(pltpu, "CompilerParams", None) or \
-            getattr(pltpu, "TPUCompilerParams")
-        return {"compiler_params": c(dimension_semantics=sem)}
+        return {"compiler_params":
+                pltpu.CompilerParams(dimension_semantics=sem)}
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,

@@ -117,9 +117,7 @@ def flash_attention_fwd(
 
     kwargs = {}
     if not interpret:
-        cp = getattr(pltpu, "CompilerParams", None) or \
-            getattr(pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = cp(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"))
     out_specs = pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0))

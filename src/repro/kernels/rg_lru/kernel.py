@@ -66,9 +66,7 @@ def rg_lru_fwd(x, r, i, lam, *, chunk: int = 256, block_w: int = 512,
     kern = functools.partial(_rglru_kernel, q=chunk)
     kwargs = {}
     if not interpret:
-        cp = getattr(pltpu, "CompilerParams", None) or \
-            getattr(pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = cp(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     return pl.pallas_call(
         kern,

@@ -81,9 +81,7 @@ def ssd_scan_fwd(x, dt, A, Bm, Cm, *, chunk: int = 128,
     kern = functools.partial(_ssd_kernel, q=chunk)
     kwargs = {}
     if not interpret:
-        cp = getattr(pltpu, "CompilerParams", None) or \
-            getattr(pltpu, "TPUCompilerParams")
-        kwargs["compiler_params"] = cp(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     y = pl.pallas_call(
         kern,

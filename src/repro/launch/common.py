@@ -19,9 +19,29 @@ store spec.
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 from typing import Optional, Tuple
 
 from repro.api import CheckpointSession, Policy
+
+# the checkout root (src/repro/launch/common.py -> three levels up)
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    wins: no other path is set. Otherwise the cache lives at the fixed
+    path ``<checkout>/.jax_cache`` — the path is part of what a later
+    process must find again, so it is never per-process or temporary."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        import jax
+        path = str(CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def add_store_args(ap: argparse.ArgumentParser, *,

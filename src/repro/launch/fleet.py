@@ -24,6 +24,7 @@ import numpy as np
 
 from repro.configs import registry as cfg_registry
 from repro.core.migration import FleetRouter
+from repro.launch.common import use_compile_cache
 from repro.models import model as M
 from repro.serving.engine import ServingEngine
 from repro.serving.traffic import TrafficGenerator
@@ -97,6 +98,7 @@ def main(argv=None) -> int:
         print(err, file=sys.stderr)
         return 2
 
+    use_compile_cache()
     cfg = cfg_registry.resolve_config(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     mesh = jax.sharding.Mesh(

@@ -1,51 +1,31 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION, not a module-level constant: importing this module must never
-touch jax device state (the dry-run sets XLA_FLAGS before first init; smoke
-tests run with the single real CPU device).
+FUNCTIONS, not module-level constants: importing this module must never
+touch jax device state (the dry-run sets XLA_FLAGS before first init;
+smoke tests run with the single real CPU device).
+
+Every mesh is built with ``Auto`` axes. ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which an op whose output sharding is ambiguous
+(the embedding gather on a ``[vocab@model, d@data]`` table) raises
+instead of letting the partitioner choose; the models are written for
+the partitioner's choice plus ``with_sharding_constraint`` hints.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
-import numpy as np
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence] = None):
+    """A mesh over ``devices`` (default: all of them) with Auto axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (tests, elastic restore targets)."""
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh(n_data: Optional[int] = None, n_model: Optional[int] = None):
-    """Best-effort mesh over whatever devices exist (tests/examples).
-    Defaults to putting all devices on the data axis."""
-    devs = jax.devices()
-    n = len(devs)
-    if n_data is None and n_model is None:
-        n_data, n_model = n, 1
-    elif n_data is None:
-        n_data = n // n_model
-    elif n_model is None:
-        n_model = n // n_data
-    assert n_data * n_model == n, (n_data, n_model, n)
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
-
-
-def mesh_axes(mesh) -> Tuple[str, ...]:
-    return tuple(mesh.axis_names)
-
-
-def batch_axes_of(mesh) -> Tuple[str, ...]:
-    """Data-parallel axes: pod (if present) folded into data."""
-    return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
-
-
-def mesh_devices(mesh) -> int:
-    return int(np.prod(list(mesh.shape.values())))
+    return make_mesh(shape, axes)

@@ -37,7 +37,8 @@ from repro.configs import registry as cfg_registry
 from repro.core import IncidentLog
 from repro.launch.common import (add_store_args, build_session,
                                  parse_resume_arg, resolve_store,
-                                 restore_timings_line, validate_resume)
+                                 restore_timings_line, use_compile_cache,
+                                 validate_resume)
 from repro.launch.supervise import (SimWorldDriver, add_supervise_args,
                                     parse_churn_args, parse_drain_arg,
                                     parse_supervise_args)
@@ -136,6 +137,7 @@ def main(argv=None) -> int:
             return 2
 
     # arguments are sound — now pay jax init + param construction
+    use_compile_cache()
     cfg = cfg_registry.resolve_config(args.arch)
     params = M.init_params(cfg, jax.random.PRNGKey(args.seed))
     n_dev = len(jax.devices())

@@ -39,7 +39,8 @@ import jax
 from repro.core import FailureAction, IncidentLog
 from repro.launch.common import (add_store_args, build_session,
                                  parse_resume_arg, resolve_store,
-                                 restore_timings_line, validate_resume)
+                                 restore_timings_line, use_compile_cache,
+                                 validate_resume)
 from repro.launch.supervise import (SimWorldDriver, add_supervise_args,
                                     parse_churn_args, parse_drain_arg,
                                     parse_supervise_args)
@@ -86,6 +87,7 @@ def main(argv=None) -> int:
         print(err, file=sys.stderr)
         return 2
 
+    use_compile_cache()
     n_dev = len(jax.devices())
     d = args.data_mesh or (n_dev // args.model_mesh)
     sess, err = build_session(spec, "launch", interval=args.ckpt_every,

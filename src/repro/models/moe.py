@@ -155,11 +155,10 @@ def moe_forward(
                 yb = jax.lax.psum(yb, ctx.model_axis)
             return yb
 
-        from repro.parallel.context import shard_map_compat
-        y = shard_map_compat(
+        y = jax.shard_map(
             shard_fn, mesh=ctx.mesh,
             in_specs=(tok_spec, gate_spec, gate_spec, w_spec, w_spec, w_spec),
-            out_specs=tok_spec,
+            out_specs=tok_spec, check_vma=False,
         )(x, gates, idx, p["w_gate"], p["w_up"], p["w_down"])
 
     if cfg.n_shared_experts:

@@ -73,17 +73,6 @@ def _causal_conv(x: jax.Array, w: jax.Array, b: jax.Array,
     return jax.nn.silu(y + b), new_state
 
 
-def _segsum(dA: jax.Array) -> jax.Array:
-    """Stable segment-sum: out[..., i, j] = sum_{j<t<=i} dA[..., t] (causal).
-
-    dA: [..., Q]; returns [..., Q, Q] with -inf above the diagonal."""
-    q = dA.shape[-1]
-    cs = jnp.cumsum(dA, axis=-1)
-    diff = cs[..., :, None] - cs[..., None, :]  # sum over (j, i]
-    ii, jj = jnp.meshgrid(jnp.arange(q), jnp.arange(q), indexing="ij")
-    return jnp.where(ii >= jj, diff, -jnp.inf)
-
-
 def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array,
                 Bm: jax.Array, Cm: jax.Array,
                 chunk: int = CHUNK,
@@ -119,7 +108,10 @@ def ssd_chunked(x: jax.Array, dt: jax.Array, A: jax.Array,
         diff = seg[..., :, None] - seg[..., None, :]
         ii = jnp.arange(q)
         causal = ii[:, None] >= ii[None, :]
-        L = jnp.where(causal, jnp.exp(diff), 0.0)   # [B,H,q,q]
+        # mask before exp: above the diagonal diff is a positive sum
+        # that overflows exp to inf over a long chunk, and the masked
+        # inf would still turn the gradient to 0 * inf = nan
+        L = jnp.exp(jnp.where(causal, diff, -jnp.inf))   # [B,H,q,q]
         scores = jnp.einsum("bin,bjn->bij", cc, bc)  # [B,q,q]
         y_intra = jnp.einsum("bhij,bjhp->bihp", scores[:, None] * L, xdt)
 

@@ -16,18 +16,6 @@ from typing import Optional, Tuple
 import jax
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checking off, across jax versions:
-    top-level `jax.shard_map(check_vma=...)` is 0.6+; older releases ship
-    it as `jax.experimental.shard_map.shard_map(check_rep=...)`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 @dataclass(frozen=True)
 class MeshContext:
     mesh: object                      # jax.sharding.Mesh (or AbstractMesh)

@@ -85,10 +85,9 @@ def pipeline_forward(
         P(*((None,) + tuple(extra_specs))),
     )
     out_specs = P(*((None,) + tuple(extra_specs)))
-    from repro.parallel.context import shard_map_compat
-    return shard_map_compat(
+    return jax.shard_map(
         stage_fn, mesh=mesh,
-        in_specs=in_specs, out_specs=out_specs,
+        in_specs=in_specs, out_specs=out_specs, check_vma=False,
     )(stacked_params, x_microbatches)
 
 

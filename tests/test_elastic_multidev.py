@@ -36,6 +36,7 @@ def test_elastic_restore_different_mesh(subproc):
     out = subproc("""
     import tempfile, numpy as np
     from repro.core import CheckpointManager, LocalFSBackend
+    from repro.launch.mesh import make_mesh
     from repro.train.loop import Trainer, TrainJob
     job = TrainJob(arch="qwen2.5-32b-smoke", shape_key="train_s16_b4")
     root = tempfile.mkdtemp()
@@ -46,10 +47,9 @@ def test_elastic_restore_different_mesh(subproc):
     t.save(block=True)
     d0 = t.params_digest()
     del t
-    import jax
     results = {}
     for shape in [(4,2),(2,2),(1,1)]:
-        t2 = Trainer.restore(mgr, mesh_factory=lambda s=shape: jax.make_mesh(s, ("data","model")))
+        t2 = Trainer.restore(mgr, mesh_factory=lambda s=shape: make_mesh(s, ("data","model")))
         assert int(t2.upper.get("step")) == 2
         assert t2.params_digest() == d0, (shape, "restore must be exact")
         results[shape] = t2.train_steps(1)["loss"]
@@ -66,6 +66,7 @@ def test_moe_expert_parallel_matches_local(subproc):
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import get_smoke_config
     from repro.models import model as M
+    from repro.launch.mesh import make_mesh
     from repro.parallel import context as pctx
     # capacity high enough that no token drops: capacity-factor MoE
     # output is otherwise legitimately sharding-dependent (which tokens
@@ -74,10 +75,10 @@ def test_moe_expert_parallel_matches_local(subproc):
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     B, S = 4, 16
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (B,S), 0, cfg.vocab_size)}
-    mesh1 = jax.make_mesh((1,1), ("data","model"), devices=jax.devices()[:1])
+    mesh1 = make_mesh((1,1), ("data","model"), devices=jax.devices()[:1])
     with pctx.mesh_context(mesh1):
         ref, _ = jax.jit(lambda p,b: M.forward_train(cfg,p,b))(params, batch)
-    mesh = jax.make_mesh((2,4), ("data","model"))
+    mesh = make_mesh((2,4), ("data","model"))
     with pctx.mesh_context(mesh):
         out, _ = jax.jit(lambda p,b: M.forward_train(cfg,p,b))(params, batch)
     np.testing.assert_allclose(np.asarray(ref,np.float32), np.asarray(out,np.float32), rtol=5e-2, atol=5e-2)
@@ -97,7 +98,8 @@ def test_dryrun_machinery_small_mesh(subproc):
     from repro.train import step as step_lib
     from repro.serving import engine as engine_lib
     from repro.launch.hlo_analysis import analyze_hlo, roofline_terms
-    mesh = jax.make_mesh((2,4), ("data","model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,4), ("data","model"))
     cfg = R.get_smoke_config("qwen1.5-110b")
     for shape_key in ["train_s64_b8", "prefill_s64_b8", "decode_s64_b8"]:
         shape = R.get_shape(shape_key)
@@ -134,15 +136,15 @@ def test_grad_compression_shard_map(subproc):
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.optim.compression import compressed_psum, init_error_feedback
-    from repro.parallel.context import shard_map_compat
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 1024), jnp.float32)
     ef = jnp.zeros((8, 1024), jnp.float32)
     def f(gl, el):
         red, e2 = compressed_psum({"w": gl[0]}, {"w": el[0]}, "data")
         return red["w"][None], e2["w"][None]
-    red, e2 = jax.jit(shard_map_compat(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                      out_specs=(P("data"), P("data"))))(g, ef)
+    red, e2 = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+                      out_specs=(P("data"), P("data")), check_vma=False))(g, ef)
     exact = jnp.mean(g, axis=0)
     got = np.asarray(red[0])
     err = np.abs(got - np.asarray(exact)).max()
@@ -157,8 +159,9 @@ def test_pipeline_parallel_matches_scan(subproc):
     """GPipe over a stage axis == plain scan over layers (toy blocks)."""
     out = subproc("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.parallel.pipeline import pipeline_forward, bubble_fraction
-    mesh = jax.make_mesh((4, 2), ("pod", "data"))
+    mesh = make_mesh((4, 2), ("pod", "data"))
     L, M, mb, D = 8, 6, 4, 16
     rng = jax.random.PRNGKey(0)
     W = jax.random.normal(rng, (L, D, D), jnp.float32) * 0.2

@@ -175,3 +175,21 @@ def test_long_500k_applicability():
     subq = {a for a in ARCH_IDS
             if any(s.name == "long_500k" for s in shapes_for(get_config(a)))}
     assert subq == {"mamba2-780m", "recurrentgemma-9b"}
+
+
+def test_ssd_grads_finite_over_full_chunk():
+    """A 256-token SSD chunk at the published mamba2 widths: the decay
+    above the diagonal overflows exp, and a mask applied after the exp
+    turns the backward into 0 * inf = nan. Grads must stay finite."""
+    from repro.train.step import make_loss_fn
+    cfg = get_config("mamba2-780m").replace(n_layers=1, vocab_size=256)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (1, 257), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
+    loss_fn = make_loss_fn(cfg, M.CallOptions())
+    (loss, _), g = jax.jit(jax.value_and_grad(loss_fn, has_aux=True))(
+        params, batch)
+    assert np.isfinite(float(loss))
+    for path, v in jax.tree_util.tree_flatten_with_path(g)[0]:
+        assert bool(jnp.all(jnp.isfinite(v))), jax.tree_util.keystr(path)

@@ -255,3 +255,33 @@ def test_result_timeout_raises_builtin_timeout_error(tmp_path):
     manifest = h.result()             # eventually commits fine
     assert manifest["step"] == 1
     mgr.close()
+
+
+def test_device_capture_chain_with_read_only_staging(tmp_path, monkeypatch):
+    """The capture path of an attached TPU, with the Pallas kernels in
+    interpret mode: the staged copy of a jax leaf is the read-only
+    buffer ``device_get`` returns, and it becomes the pinned mirror that
+    a sparse link patches in place. Every link must still encode and
+    every step restore bit-identically, through the fingerprint and
+    fused kernels."""
+    import jax.numpy as jnp
+    from repro.core import async_snapshot
+    monkeypatch.setattr(async_snapshot, "_BACKEND", "tpu")
+    rng = np.random.RandomState(3)
+    w = rng.randn(16 * CB // 4).astype(np.float32)
+    mgr = _mgr(tmp_path, sparse_min_bytes=CB)
+    up = UpperHalf()
+    want = {}
+    for s in range(1, 5):
+        w[rng.randint(0, w.size, size=3)] += 1.0
+        up.register("params", "params",
+                    {"w": jnp.asarray(w), "b": jnp.asarray(w[:CB // 4])})
+        mgr.save(s, up, OpLog())
+        want[s] = w.copy()
+    assert mgr.stats["fp_kernel_leaves"] == 2    # the base's baselines
+    assert mgr.stats["fused_leaves"] == 6        # two leaves, three links
+    assert mgr.stats["host_fp_leaves"] == 0
+    for s, v in want.items():
+        _, entries = async_snapshot.materialize_manifest_chain(
+            mgr.backend, s)
+        np.testing.assert_array_equal(entries["params"]["['w']"], v)

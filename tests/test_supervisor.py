@@ -633,6 +633,7 @@ def test_shrink_onto_smaller_mesh_multidevice(subproc):
     import tempfile, numpy as np, jax
     from repro.core import (CheckpointManager, ClusterSupervisor,
                             FailureAction, LocalFSBackend)
+    from repro.launch.mesh import make_mesh
     from repro.train.loop import Trainer, TrainJob
     job = TrainJob(arch="phi4-mini-3.8b-smoke", shape_key="train_s16_b4")
     root = tempfile.mkdtemp()
@@ -648,7 +649,7 @@ def test_shrink_onto_smaller_mesh_multidevice(subproc):
     def restore(target):
         return Trainer.restore(
             mgr, step=target.step,
-            mesh_factory=lambda: jax.make_mesh((2, 1), ("data", "model")),
+            mesh_factory=lambda: make_mesh((2, 1), ("data", "model")),
             rewrite_op=target.rewrite_op())
     sup = ClusterSupervisor([0, 1, 2, 3], manager=mgr,
                             heartbeat_timeout=3.0, clock=lambda: t[0],
