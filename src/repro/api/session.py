@@ -29,6 +29,7 @@ from repro.api.app import RestoreContext, validate_app
 from repro.api.errors import PolicyError
 from repro.api.policy import Policy
 from repro.api.registry import resolve_app_kind, resolve_backend
+from repro.spans import span
 
 
 class CheckpointSession:
@@ -120,15 +121,18 @@ class CheckpointSession:
         in-flight ``SnapshotHandle`` (None when blocking or dropped under
         "skip" backpressure)."""
         app = self._require_app()
-        state_fn = getattr(app, "session_state", None)
-        state = state_fn() if callable(state_fn) else app.checkpoint_state()
-        log_fn = getattr(app, "runtime_log", None)
-        if callable(log_fn):
-            log = log_fn()
-        else:
-            from repro.core.oplog import OpLog
-            log = OpLog()
-        return self.manager.save(int(app.checkpoint_step()), state, log,
+        step = int(app.checkpoint_step())
+        with span("ckpt.state", step=step):
+            state_fn = getattr(app, "session_state", None)
+            state = (state_fn() if callable(state_fn)
+                     else app.checkpoint_state())
+            log_fn = getattr(app, "runtime_log", None)
+            if callable(log_fn):
+                log = log_fn()
+            else:
+                from repro.core.oplog import OpLog
+                log = OpLog()
+        return self.manager.save(step, state, log,
                                  block=block, job_meta=dict(app.job_meta()))
 
     def maybe_snapshot(self, *, final: bool = False):

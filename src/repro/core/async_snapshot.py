@@ -75,6 +75,7 @@ from repro.api.errors import PolicyError, RestoreError, SnapshotError
 from repro.core.backends.base import CheckpointBackend
 from repro.core import delta as deltamod
 from repro.core.oplog import OpLog
+from repro.spans import add, span
 from repro.core.split_state import UpperHalf, flatten_with_paths
 from repro.kernels.ckpt_codec.ref import FP_CHUNK_BYTES, FP_SEG_BYTES
 
@@ -175,8 +176,9 @@ class _SparseCtx:
             # single-device leaves only: a sharded array would be
             # replicated by the kernel call — host path handles those
             from repro.kernels.ckpt_codec import ops
-            return ("tpu", ops.chunk_fingerprints(v, self.chunk_bytes),
-                    weakref.ref(v))
+            with span("ckpt.capture.kernel"):
+                fp = ops.chunk_fingerprints(v, self.chunk_bytes)
+            return "tpu", fp, weakref.ref(v)
         if host_bytes is None:
             host_bytes = _leaf_bytes(v)
         fp = _fp_host_threaded(host_bytes, self.chunk_bytes,
@@ -202,9 +204,25 @@ class _SparseCtx:
             del self.fp[key]
 
 
-def _leaf_bytes(v) -> np.ndarray:
+def _to_host(x, st: Optional[Dict[str, Any]] = None):
+    """``jax.device_get(x)`` for a capture. With ``st``, the copy of the
+    device arrays in ``x`` is one ``ckpt.capture.d2h`` span whose seconds
+    and bytes add to ``st["d2h_seconds"]``/``st["d2h_bytes"]``; the wait
+    for the work that produces ``x`` (a capture kernel, the last model
+    step) comes first, outside it, so the seconds are the transfer's."""
     import jax
-    host = np.asarray(jax.device_get(v))
+    n = sum(a.nbytes for a in jax.tree.leaves(x) if isinstance(a, jax.Array))
+    if st is None or not n:
+        return jax.device_get(x)
+    jax.block_until_ready(x)
+    with span("ckpt.capture.d2h", into=st, key="d2h_seconds", bytes=n):
+        out = jax.device_get(x)
+    st["d2h_bytes"] += n
+    return out
+
+
+def _leaf_bytes(v, st: Optional[Dict[str, Any]] = None) -> np.ndarray:
+    host = np.asarray(_to_host(v, st))
     return np.ascontiguousarray(host).reshape(-1).view(np.uint8)
 
 
@@ -252,14 +270,15 @@ class _StagingSlot:
         With a sparse context, eligible leaves take the dirty-chunk
         path instead of a full copy (``_try_sparse``); everything else
         falls through to the dense copy and refreshes its fingerprint
-        baseline for the next capture."""
-        import jax
+        baseline for the next capture. Every blocking device->host copy
+        adds its seconds and bytes to ``d2h_seconds``/``d2h_bytes``."""
         accel = _backend() != "cpu"
         out: Dict[str, Dict[str, Any]] = {}
         st = {"capture_bytes": 0, "dirty_chunks": 0, "clean_chunks": 0,
               "identity_skips": 0, "sparse_leaves": 0,
               "fp_kernel_leaves": 0, "fused_leaves": 0,
-              "fused_overflows": 0, "host_fp_leaves": 0}
+              "fused_overflows": 0, "host_fp_leaves": 0,
+              "d2h_bytes": 0, "d2h_seconds": 0.0}
         for name, e in upper.items():
             pool = self.buffers.setdefault(name, {})
             taken: Dict[str, Any] = {}
@@ -272,7 +291,7 @@ class _StagingSlot:
                     if sp is not None:
                         taken[path] = sp
                         continue
-                a = np.asarray(jax.device_get(v))
+                a = np.asarray(_to_host(v, st))
                 if not (accel and a is not v
                         and not isinstance(v, np.ndarray)):
                     # not already a private copy: stage into this slot's
@@ -282,7 +301,8 @@ class _StagingSlot:
                             or buf.dtype != a.dtype:
                         buf = np.empty(a.shape, a.dtype)
                         pool[path] = buf
-                    np.copyto(buf, a)
+                    with span("ckpt.capture.stage"):
+                        np.copyto(buf, a)
                     a = buf
                 taken[path] = a
                 st["capture_bytes"] += a.nbytes
@@ -327,14 +347,16 @@ class _StagingSlot:
             from repro.kernels.ckpt_codec import ops
             # fused single pass: 1 kernel launch + 1 blocking D2H (the
             # two-launch gather path is its internal overflow fallback)
-            fp_new, idx, compact = ops.fused_dirty_chunk_capture(
-                v, fpe.fp, cb, capacity_hint=fpe.last_dirty)
+            with span("ckpt.capture.kernel"):
+                fp_new, idx, compact = ops.fused_dirty_chunk_capture(
+                    v, fpe.fp, cb, capacity_hint=fpe.last_dirty,
+                    to_host=lambda x: _to_host(x, st))
             wref = weakref.ref(v)
             st["fused_leaves"] += 1
             if idx.size > ops.fused_capacity(n_chunks, cb, fpe.last_dirty):
                 st["fused_overflows"] += 1   # finished by the gather path
         elif fpe.impl == "host":
-            buf = _leaf_bytes(v)
+            buf = _leaf_bytes(v, st)
             fp_new = _fp_host_threaded(buf, cb, ctx.pool, ctx.workers)
             st["host_fp_leaves"] += 1
             idx = np.nonzero(np.any(fp_new != fpe.fp, axis=1))[0]
@@ -379,7 +401,6 @@ class _Captured:
     kinds: Dict[str, str]
     log_json: Any
     job_meta: Dict[str, Any]
-    capture_seconds: float
     sparse_committed: bool = False  # set by encode: mirror was patched
 
 
@@ -494,7 +515,7 @@ class AsyncSnapshotter:
                     max_workers=self._fp_workers,
                     thread_name_prefix="snap-fp")
         self.stats: Dict[str, Any] = {
-            "saves": 0, "skipped": 0, "failed": 0, "chain_links": 0,
+            "saves": 0, "skipped": 0, "failed": 0,
             "bytes_written": 0, "bytes_logical": 0, "bytes_encoded": 0,
             "capture_bytes": 0, "sparse_leaves": 0, "identity_skips": 0,
             "dirty_chunks": 0, "clean_chunks": 0,
@@ -504,8 +525,18 @@ class AsyncSnapshotter:
             # or the host fingerprint (either)
             "fp_kernel_leaves": 0, "fused_leaves": 0, "fused_overflows": 0,
             "host_fp_leaves": 0,
-            "save_seconds": 0.0, "capture_seconds": 0.0,
-            "encode_commit_seconds": 0.0,
+            # seconds of each phase, read from its span (repro.spans):
+            # capture on the caller thread, of it the blocking
+            # device->host copies (with their bytes), and the wait for a
+            # free staging slot before it; encode + commit on the encode
+            # thread, of it the commit (last writes + manifest), and of
+            # the rest the thread's CPU time and its waits for a free
+            # in-flight write; blob writes summed over the writer threads
+            "capture_seconds": 0.0, "d2h_seconds": 0.0, "d2h_bytes": 0,
+            "slot_wait_seconds": 0.0,
+            "encode_commit_seconds": 0.0, "encode_cpu_seconds": 0.0,
+            "encode_put_wait_seconds": 0.0, "commit_seconds": 0.0,
+            "write_seconds": 0.0,
         }
 
     # --- capture (caller thread) ------------------------------------------
@@ -520,7 +551,9 @@ class AsyncSnapshotter:
                         return s
                 if self.backpressure == "skip" and not must_take:
                     return None
-                self._cond.wait()
+                with span("ckpt.capture.slot_wait", into=self.stats,
+                          key="slot_wait_seconds"):
+                    self._cond.wait()
 
     def _release_slot(self, slot: _StagingSlot) -> None:
         with self._cond:
@@ -558,20 +591,21 @@ class AsyncSnapshotter:
                 pool=self._fp_pool,
                 workers=self._fp_workers,
             )
-        t0 = time.monotonic()
         try:
-            host_state, cap_st = slot.capture(upper, ctx)
-            cap = _Captured(
-                step=step,
-                slot=slot,
-                host_state=host_state,
-                structure=upper.structure(),
-                kinds={name: e.kind for name, e in upper.items()},
-                log_json=(oplog.prune() if self.prune_oplog
-                          else oplog).to_json(),
-                job_meta=job_meta or {},
-                capture_seconds=time.monotonic() - t0,
-            )
+            with span("ckpt.capture", step=step) as sp:
+                host_state, cap_st = slot.capture(upper, ctx)
+                with span("ckpt.capture.oplog"):
+                    log_json = (oplog.prune() if self.prune_oplog
+                                else oplog).to_json()
+                cap = _Captured(
+                    step=step,
+                    slot=slot,
+                    host_state=host_state,
+                    structure=upper.structure(),
+                    kinds={name: e.kind for name, e in upper.items()},
+                    log_json=log_json,
+                    job_meta=job_meta or {},
+                )
         except BaseException:
             if ctx is not None:
                 # a partial capture may have advanced some leaves'
@@ -586,8 +620,8 @@ class AsyncSnapshotter:
             self._cap_chain_len = self._cap_chain_len + 1 if ctx.chain else 0
             self._fp_step = step
         handle = SnapshotHandle(step)
-        handle.timings["capture"] = cap.capture_seconds
-        self.stats["capture_seconds"] += cap.capture_seconds
+        handle.timings["capture"] = sp.seconds
+        self.stats["capture_seconds"] += sp.seconds
         for k, n in cap_st.items():
             self.stats[k] += n
         with self._cond:
@@ -599,9 +633,9 @@ class AsyncSnapshotter:
 
     def _encode_and_commit(self, cap: _Captured,
                            handle: SnapshotHandle) -> None:
-        t0 = time.monotonic()
         try:
-            manifest = self._do_encode_commit(cap)
+            with span("ckpt.encode", step=cap.step) as sp:
+                manifest = self._do_encode_commit(cap)
         except BaseException as e:
             with self._cond:
                 self._last_error = e   # drain() re-raises even if the
@@ -613,11 +647,9 @@ class AsyncSnapshotter:
             self._retire(cap.slot, handle, keep_as_prev=False)
             handle._future.set_exception(e)
             return
-        dt = time.monotonic() - t0
-        handle.timings["encode_commit"] = dt
+        handle.timings["encode_commit"] = sp.seconds
         self.stats["saves"] += 1
-        self.stats["encode_commit_seconds"] += dt
-        self.stats["save_seconds"] += cap.capture_seconds + dt
+        self.stats["encode_commit_seconds"] += sp.seconds
         self._retire(cap.slot, handle,
                      keep_as_prev=self.delta_base_interval > 1,
                      step=cap.step, host_state=cap.host_state,
@@ -625,6 +657,7 @@ class AsyncSnapshotter:
         handle._future.set_result(manifest)
 
     def _do_encode_commit(self, cap: _Captured) -> Dict[str, Any]:
+        cpu0 = time.thread_time()
         chain = (self.delta_base_interval > 1 and self._prev is not None
                  and self._chain_len < self.delta_base_interval - 1)
         base_step = self._prev[0] if chain else None
@@ -648,55 +681,58 @@ class AsyncSnapshotter:
             codec = self.codec_by_kind.get(cap.kinds[name])
             leaf_metas: Dict[str, Any] = {}
             for path, arr in leaves.items():
-                if isinstance(arr, _SparseLeaf):
-                    if arr.base_step != base_step:
-                        raise SnapshotError(
-                            f"sparse capture of {name}:{path} is relative "
-                            f"to step {arr.base_step}, but the encode "
-                            f"chain base is {base_step}")
-                    prev_arr = base_state.get(name, {}).get(path)
-                    if prev_arr is None:
-                        raise SnapshotError(
-                            f"sparse capture of {name}:{path} has no "
-                            "previous value in the pinned mirror")
-                    if not prev_arr.flags.writeable:
-                        # staged straight from device_get (read-only);
-                        # the mirror is patched in place from here on
-                        prev_arr = base_state[name][path] = \
-                            np.array(prev_arr)
-                    m = deltamod.encode_leaf_sparse(
-                        arr.shape, arr.dtype, arr.chunk_bytes,
-                        arr.n_chunks, arr.dirty_idx,
-                        arr.dirty_bytes if arr.dirty_bytes is not None
-                        else np.empty((0, arr.chunk_bytes), np.uint8),
-                        prev_arr, writer.put, writer.has,
-                        compress=self.compress)
-                    logical += arr.nbytes
-                else:
-                    prev_arr = None
-                    if chain and not deltamod.codec_applicable(arr, codec):
+                with span("ckpt.encode.leaf", path=f"{name}:{path}",
+                          bytes=int(arr.nbytes)):
+                    if isinstance(arr, _SparseLeaf):
+                        if arr.base_step != base_step:
+                            raise SnapshotError(
+                                f"sparse capture of {name}:{path} is "
+                                f"relative to step {arr.base_step}, but "
+                                f"the encode chain base is {base_step}")
                         prev_arr = base_state.get(name, {}).get(path)
-                    m = deltamod.encode_leaf(
-                        arr, writer.put, writer.has,
-                        codec=codec, prev=prev_arr, compress=self.compress)
-                    logical += arr.nbytes
-                    if has_sparse:
-                        # the old prev slot stays pinned as the mirror;
-                        # fold this dense leaf's bytes into it so the
-                        # mirror is the complete current snapshot (the
-                        # staged copy belongs to a slot about to be
-                        # freed, so take a private copy)
-                        mirror = base_state.setdefault(name, {})
-                        old = mirror.get(path)
-                        if old is not None and old.shape == arr.shape \
-                                and old.dtype == arr.dtype \
-                                and old.flags.writeable:
-                            np.copyto(old, arr)
-                        else:
-                            mirror[path] = np.array(arr)
-                written += m.pop("bytes_written", 0)
-                encoded += m.pop("bytes_encoded", 0)
-                leaf_metas[path] = m
+                        if prev_arr is None:
+                            raise SnapshotError(
+                                f"sparse capture of {name}:{path} has no "
+                                "previous value in the pinned mirror")
+                        if not prev_arr.flags.writeable:
+                            # staged straight from device_get (read-only);
+                            # the mirror is patched in place from here on
+                            prev_arr = base_state[name][path] = \
+                                np.array(prev_arr)
+                        m = deltamod.encode_leaf_sparse(
+                            arr.shape, arr.dtype, arr.chunk_bytes,
+                            arr.n_chunks, arr.dirty_idx,
+                            arr.dirty_bytes if arr.dirty_bytes is not None
+                            else np.empty((0, arr.chunk_bytes), np.uint8),
+                            prev_arr, writer.put, writer.has,
+                            compress=self.compress)
+                        logical += arr.nbytes
+                    else:
+                        prev_arr = None
+                        if chain and not deltamod.codec_applicable(arr,
+                                                                   codec):
+                            prev_arr = base_state.get(name, {}).get(path)
+                        m = deltamod.encode_leaf(
+                            arr, writer.put, writer.has, codec=codec,
+                            prev=prev_arr, compress=self.compress)
+                        logical += arr.nbytes
+                        if has_sparse:
+                            # the old prev slot stays pinned as the mirror;
+                            # fold this dense leaf's bytes into it so the
+                            # mirror is the complete current snapshot (the
+                            # staged copy belongs to a slot about to be
+                            # freed, so take a private copy)
+                            mirror = base_state.setdefault(name, {})
+                            old = mirror.get(path)
+                            if old is not None and old.shape == arr.shape \
+                                    and old.dtype == arr.dtype \
+                                    and old.flags.writeable:
+                                np.copyto(old, arr)
+                            else:
+                                mirror[path] = np.array(arr)
+                    written += m.pop("bytes_written", 0)
+                    encoded += m.pop("bytes_encoded", 0)
+                    leaf_metas[path] = m
             entries_manifest[name] = {"kind": cap.kinds[name],
                                       "leaves": leaf_metas}
         if has_sparse:
@@ -708,22 +744,26 @@ class AsyncSnapshotter:
                     continue
                 for path in [p for p in base_state[name] if p not in cur]:
                     del base_state[name][path]
-        writer.drain()  # every blob durable before the manifest commits
-        manifest = {
-            "format": (SPARSE_MANIFEST_FORMAT if has_sparse
-                       else MANIFEST_FORMAT),
-            "step": cap.step,
-            "base_step": base_step,
-            "entries": entries_manifest,
-            "oplog": cap.log_json,
-            "structure": cap.structure,
-            "job": cap.job_meta,
-        }
-        cap.sparse_committed = has_sparse
-        self.backend.commit_manifest(cap.step, manifest)
+        cpu = time.thread_time() - cpu0   # the encode's, before the commit
+        with span("ckpt.commit", step=cap.step, into=self.stats,
+                  key="commit_seconds"):
+            writer.drain()  # every blob durable before the manifest commits
+            manifest = {
+                "format": (SPARSE_MANIFEST_FORMAT if has_sparse
+                           else MANIFEST_FORMAT),
+                "step": cap.step,
+                "base_step": base_step,
+                "entries": entries_manifest,
+                "oplog": cap.log_json,
+                "structure": cap.structure,
+                "job": cap.job_meta,
+            }
+            cap.sparse_committed = has_sparse
+            self.backend.commit_manifest(cap.step, manifest)
         self._chain_len = self._chain_len + 1 if chain else 0
-        if chain:
-            self.stats["chain_links"] += 1
+        for k, v in writer.counts.items():
+            self.stats[k] += v
+        self.stats["encode_cpu_seconds"] += cpu
         self.stats["bytes_written"] += written
         self.stats["bytes_logical"] += logical
         self.stats["bytes_encoded"] += encoded
@@ -837,7 +877,12 @@ class _BlobWriter:
     ``has`` answers "is this blob durable or already queued by me" —
     the backend alone can't, because a queued write hasn't landed yet,
     and asking it directly would re-write (and re-count) every repeated
-    chunk within one snapshot (e.g. zero-initialized weights)."""
+    chunk within one snapshot (e.g. zero-initialized weights).
+
+    ``counts`` holds the seconds the writer threads spent in
+    ``put_blob`` (``write_seconds``) and the seconds ``put`` blocked the
+    encode thread for a free in-flight slot (``encode_put_wait_seconds``),
+    for the pipeline to fold into its stats once the blobs are durable."""
 
     def __init__(self, backend: CheckpointBackend,
                  pool: ThreadPoolExecutor,
@@ -847,18 +892,25 @@ class _BlobWriter:
         self._sem = threading.Semaphore(max_pending)
         self._futures: List[Future] = []
         self._queued: set = set()  # touched only by the encode thread
+        self.counts = {"write_seconds": 0.0, "encode_put_wait_seconds": 0.0}
 
     def has(self, name: str) -> bool:
         return name in self._queued or self._backend.has_blob(name)
 
     def put(self, name: str, data: bytes) -> None:
         self._queued.add(name)
-        self._sem.acquire()
+        if not self._sem.acquire(blocking=False):
+            t0 = time.perf_counter()
+            self._sem.acquire()
+            self.counts["encode_put_wait_seconds"] += \
+                time.perf_counter() - t0
         self._futures.append(self._pool.submit(self._write, name, data))
 
     def _write(self, name: str, data: bytes) -> None:
         try:
-            self._backend.put_blob(name, data)
+            with span("ckpt.write", bytes=len(data), into=self.counts,
+                      key="write_seconds"):
+                self._backend.put_blob(name, data)
         finally:
             self._sem.release()
 
@@ -922,14 +974,31 @@ def leaf_chain_start(manifests: List[Dict[str, Any]], name: str,
 
 
 def _decode_chain_leaf(manifests: List[Dict[str, Any]], backend,
-                       name: str, path: str) -> np.ndarray:
+                       name: str, path: str,
+                       timings: Optional[Dict[str, Any]] = None
+                       ) -> np.ndarray:
     """Decode one leaf of the final manifest from the start of its xor
-    run (``leaf_chain_start``) forward, XOR-applying each link."""
+    run (``leaf_chain_start``) forward, XOR-applying each link. With
+    ``timings``, the leaf's blob reads add to ``fetch_s`` and the rest
+    of its decode (zlib, XOR, sparse patch) to ``decode_s``."""
     i = leaf_chain_start(manifests, name, path)
+    get_blob = backend.get_blob
+    fetch_s = 0.0
+    if timings is not None:
+        def get_blob(blob: str) -> bytes:
+            nonlocal fetch_s
+            with span("ckpt.restore.fetch") as sp:
+                data = backend.get_blob(blob)
+            fetch_s += sp.seconds
+            return data
     val: Optional[np.ndarray] = None
-    for m in manifests[i:]:
-        val = deltamod.decode_leaf(
-            m["entries"][name]["leaves"][path], backend.get_blob, prev=val)
+    with span("ckpt.restore.decode", path=f"{name}:{path}") as sp:
+        for m in manifests[i:]:
+            val = deltamod.decode_leaf(
+                m["entries"][name]["leaves"][path], get_blob, prev=val)
+    if timings is not None:
+        add(timings, "fetch_s", fetch_s)
+        add(timings, "decode_s", sp.seconds - fetch_s)
     return val
 
 
@@ -940,7 +1009,7 @@ _PARALLEL_DECODE_MIN_LEAVES = 4
 
 def materialize_manifest_chain(
     backend: CheckpointBackend, step: int, workers: Optional[int] = None,
-    skip_entries=(),
+    skip_entries=(), timings: Optional[Dict[str, Any]] = None,
 ) -> Tuple[Dict[str, Any], Dict[str, Dict[str, np.ndarray]]]:
     """Delta chain -> full state. Each leaf decodes independently (its
     own xor-run walk), so leaves fan out across a worker pool — restore
@@ -955,7 +1024,9 @@ def materialize_manifest_chain(
     forces the serial path (both orders produce identical arrays).
     ``skip_entries``: entry names to leave undecoded (absent from the
     result) — a caller that rebuilds an entry from scratch, like the
-    serving engine re-slotting its KV cache, shouldn't pay its chain."""
+    serving engine re-slotting its KV cache, shouldn't pay its chain.
+    ``timings``: receives ``fetch_s`` and ``decode_s``, each summed over
+    the leaves (so over the workers, not wall time)."""
     manifests = [backend.get_manifest(s)
                  for s in manifest_chain_steps(backend, step)]
     final = manifests[-1]
@@ -969,9 +1040,10 @@ def materialize_manifest_chain(
         with ThreadPoolExecutor(max_workers=workers,
                                 thread_name_prefix="chain-decode") as pool:
             vals = list(pool.map(
-                lambda t: _decode_chain_leaf(manifests, backend, *t), tasks))
+                lambda t: _decode_chain_leaf(manifests, backend, *t,
+                                             timings), tasks))
     else:
-        vals = [_decode_chain_leaf(manifests, backend, name, path)
+        vals = [_decode_chain_leaf(manifests, backend, name, path, timings)
                 for name, path in tasks]
     entries: Dict[str, Dict[str, np.ndarray]] = {}
     for (name, path), val in zip(tasks, vals):

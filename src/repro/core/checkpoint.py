@@ -159,7 +159,8 @@ class CheckpointManager:
     def restore(self, step: Optional[int] = None,
                 workers: Optional[int] = None,
                 skip_entries=(), *, streaming: bool = False,
-                lazy_kinds=None) -> RestoredState:
+                lazy_kinds=None,
+                timings: Optional[Dict[str, Any]] = None) -> RestoredState:
         """Materialize a committed checkpoint's delta chain into host
         arrays. ``workers`` sizes the leaf-decode pool (restore latency
         matters as much as checkpoint overhead — CRIUgpu's point);
@@ -172,7 +173,10 @@ class CheckpointManager:
         ``LazyLeaves`` placeholders that keep fetching/decoding in the
         background and block their first toucher — bit-identical to the
         eager path, earlier by the cold tier's fetch+decode time. The
-        full restart lifecycle on top of this is ``core.incarnation``."""
+        full restart lifecycle on top of this is ``core.incarnation``.
+        ``timings`` (eager restore) receives the seconds of blob reads
+        (``fetch_s``) and of decoding (``decode_s``), summed over the
+        decode workers."""
         step = self.resolve_step(step)
         streamer = None
         if streaming:
@@ -186,7 +190,7 @@ class CheckpointManager:
         else:
             manifest, entries = materialize_manifest_chain(
                 self.backend, step, workers=workers,
-                skip_entries=skip_entries)
+                skip_entries=skip_entries, timings=timings)
         oplog = OpLog.from_json(manifest["oplog"])
         return RestoredState(step=step, manifest=manifest, entries=entries,
                              oplog=oplog, streamer=streamer)

@@ -30,12 +30,12 @@ id stable across the rewrite.
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Dict, List, Optional
 
 from repro.api.errors import CheckpointError
 from repro.core.checkpoint import CheckpointManager, RestoredState
 from repro.core.oplog import CacheAlloc, Compile, Op, OpLog
+from repro.spans import span
 from repro.core.split_state import LowerHalf
 from repro.core.virtual_ids import VirtualId
 
@@ -94,22 +94,27 @@ class Incarnation:
         decoded (``materialize_s`` then measures time-to-hot, the
         latency the resumed app actually waits); the cold tier keeps
         streaming behind replay and rebind, and ``stream_timings()``
-        reports the per-phase fetch/decode/fault counters."""
+        reports the per-phase fetch/decode/fault counters. An eager
+        restore splits ``materialize_s`` into ``fetch_s`` (blob reads)
+        and ``decode_s`` (zlib, XOR, sparse patch), each summed over the
+        decode workers."""
         if self.restored is not None:
             raise LifecycleError("materialize() already ran")
-        t0 = time.monotonic()
         kw: Dict[str, Any] = {}
         if self.streaming:
             kw["streaming"] = True
             if self.lazy_kinds is not None:
                 kw["lazy_kinds"] = self.lazy_kinds
-        self.restored = self.manager.restore(self.step,
-                                             workers=self.decode_workers,
-                                             skip_entries=self.skip_entries,
-                                             **kw)
+        else:
+            self.timings.update(fetch_s=0.0, decode_s=0.0)
+            kw["timings"] = self.timings
+        with span("ckpt.restore.materialize", into=self.timings,
+                  key="materialize_s"):
+            self.restored = self.manager.restore(
+                self.step, workers=self.decode_workers,
+                skip_entries=self.skip_entries, **kw)
         self.streamer = self.restored.streamer
         self.step = self.restored.step
-        self.timings["materialize_s"] = time.monotonic() - t0
         return self.restored
 
     # --- phases 1-2: fresh lower half + replay -------------------------
@@ -130,17 +135,16 @@ class Incarnation:
             raise LifecycleError("build_lower() before materialize()")
         if self.lower is not None:
             raise LifecycleError("build_lower() already ran")
-        t0 = time.monotonic()
-        lower = LowerHalf(mesh_factory=self.mesh_factory)
-        ops: List[Op] = []
-        for op in self.restored.oplog.ops:
-            if self.rewrite_op is not None:
-                op = self.rewrite_op(op)
-            lower.apply_op(op)
-            ops.append(op)
-        lower.oplog = OpLog(ops)
+        with span("ckpt.restore.replay", into=self.timings, key="replay_s"):
+            lower = LowerHalf(mesh_factory=self.mesh_factory)
+            ops: List[Op] = []
+            for op in self.restored.oplog.ops:
+                if self.rewrite_op is not None:
+                    op = self.rewrite_op(op)
+                lower.apply_op(op)
+                ops.append(op)
+            lower.oplog = OpLog(ops)
         self.lower = lower
-        self.timings["replay_s"] = time.monotonic() - t0
         return lower
 
     # --- phase 3: upper-half rebinding ---------------------------------
@@ -156,13 +160,9 @@ class Incarnation:
         if self.released:
             raise LifecycleError("payload released; bind() must run "
                                  "before release()")
-        t0 = time.monotonic()
-        mesh = self.mesh_or_none()
-        out = materialize_entry(self.restored, name, template, plan, mesh,
-                                logical)
-        self.timings["rebind_s"] = \
-            self.timings.get("rebind_s", 0.0) + time.monotonic() - t0
-        return out
+        with span("ckpt.restore.rebind", into=self.timings, key="rebind_s"):
+            return materialize_entry(self.restored, name, template, plan,
+                                     self.mesh_or_none(), logical)
 
     def scalar(self, name: str):
         """Plain scalar/int-tree entries (step counters, cursors)."""

@@ -3,7 +3,7 @@ device dispatch; interpret mode off the TPU, for tests)."""
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -212,7 +212,8 @@ def _fused_capture_impl(x: jax.Array, prev_fp: jax.Array, *,
 
 def fused_dirty_chunk_capture(
         x, prev_fp, chunk_bytes: int = FP_CHUNK_BYTES, *,
-        capacity_hint: Optional[int] = None, interpret: bool = None
+        capacity_hint: Optional[int] = None, interpret: bool = None,
+        to_host: Optional[Callable] = None
         ) -> Tuple[jax.Array, np.ndarray, Optional[np.ndarray]]:
     """Single-pass incremental capture of one leaf: exactly ONE kernel
     launch and ONE blocking device->host transfer.
@@ -230,8 +231,12 @@ def fused_dirty_chunk_capture(
 
     ``capacity_hint`` sizes the compaction buffer (chunks dirty last
     snapshot); see :func:`fused_capacity` for the clamping policy.
+    ``to_host`` makes every blocking device->host copy (a caller that
+    accounts for its transfers passes its own; ``jax.device_get`` if
+    None).
     """
     assert chunk_bytes % (4 * BLOCK) == 0, chunk_bytes
+    to_host = to_host or jax.device_get
     xd = jnp.asarray(x)
     if isinstance(prev_fp, np.ndarray):  # ref-twin callers hold u32
         prev_fp = prev_fp.view(np.int32)
@@ -241,7 +246,7 @@ def fused_dirty_chunk_capture(
         xd, prev_fp, chunk_bytes=chunk_bytes, capacity=capacity,
         interpret=interpret)
     # the one blocking hop: count + indices + compacted payload together
-    cnt_h, idx_h, compact_h = jax.device_get((cnt, idx, compact))
+    cnt_h, idx_h, compact_h = to_host((cnt, idx, compact))
     k = int(cnt_h[0])
     if k == 0:
         return fp, np.empty(0, np.int64), None
@@ -250,14 +255,14 @@ def fused_dirty_chunk_capture(
         # two-launch gather fallback, reusing the fingerprints (costs
         # the old path's extra sync — but only on the rare step whose
         # dirty count more than doubled; the caller's next hint is k)
-        mask = np.asarray(jax.device_get(_dirty_mask(fp, prev_fp)))
+        mask = np.asarray(to_host(_dirty_mask(fp, prev_fp)))
         full_idx = np.nonzero(mask)[0]
         padded = 1 << (full_idx.size - 1).bit_length()
         idxp = np.full(padded, full_idx[-1], np.int32)
         idxp[:full_idx.size] = full_idx
         gathered = _gather_chunks(xd, jnp.asarray(idxp),
                                   chunk_bytes=chunk_bytes)
-        host = np.asarray(jax.device_get(gathered))[:full_idx.size]
+        host = np.asarray(to_host(gathered))[:full_idx.size]
         return (fp, full_idx.astype(np.int64),
                 host.view(np.uint8).reshape(full_idx.size, chunk_bytes))
     dirty_idx = idx_h[:k].astype(np.int64)

@@ -42,6 +42,7 @@ from repro.parallel.planner import make_plan
 from repro.parallel import context as pctx
 from repro.serving.kv_cache import cache_shardings, abstract_cache
 from repro.core.oplog import CacheAlloc, Compile
+from repro.spans import span
 from repro.core.split_state import (LowerHalf, UpperHalf, fill_like,
                                     register_step_fn, tree_from_paths)
 from repro.train.step import make_call_options, ContextualJit
@@ -472,10 +473,11 @@ class ServingEngine:
                               np.asarray(req.out).ravel()]).astype(np.int32)
         hist = seq[:-1]
         if len(hist):
-            if self._prefill_admission:
-                self._prefill_slot(s, hist)
-            else:
-                self._replay_slot(s, hist)
+            with span("serve.admit", rid=req.rid, tokens=len(hist)):
+                if self._prefill_admission:
+                    self._prefill_slot(s, hist)
+                else:
+                    self._replay_slot(s, hist)
         self.slot_req[s] = req
         self.slot_tok[s, 0] = int(seq[-1])
         self.slot_pos[s] = len(seq) - 1
@@ -570,6 +572,10 @@ class ServingEngine:
 
     def step(self) -> int:
         """One engine iteration; returns #active slots."""
+        with span("serve.step"):
+            return self._step()
+
+    def _step(self) -> int:
         self._admit()
         self._ensure_cache()
         active = [s for s in range(self.n_slots) if self.slot_req[s]]
@@ -578,7 +584,8 @@ class ServingEngine:
         toks = jnp.asarray(self.slot_tok)
         poss = jnp.asarray(self.slot_pos)
         logits, self.cache = self.decode(self.params, self.cache, toks, poss)
-        nxt = np.asarray(jax.device_get(jnp.argmax(logits, -1)))
+        with span("serve.sync"):
+            nxt = np.asarray(jax.device_get(jnp.argmax(logits, -1)))
         for s in active:
             req = self.slot_req[s]
             tok = int(nxt[s])

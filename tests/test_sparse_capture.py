@@ -274,8 +274,11 @@ def test_device_capture_chain_with_read_only_staging(tmp_path, monkeypatch):
     want = {}
     for s in range(1, 5):
         w[rng.randint(0, w.size, size=3)] += 1.0
+        # jnp.array copies: on the CPU jnp.asarray may alias ``w``, and
+        # the next step's in-place update would then race the base's
+        # fingerprint kernel, which runs asynchronously on that buffer
         up.register("params", "params",
-                    {"w": jnp.asarray(w), "b": jnp.asarray(w[:CB // 4])})
+                    {"w": jnp.array(w), "b": jnp.array(w[:CB // 4])})
         mgr.save(s, up, OpLog())
         want[s] = w.copy()
     assert mgr.stats["fp_kernel_leaves"] == 2    # the base's baselines

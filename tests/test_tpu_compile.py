@@ -11,7 +11,10 @@ The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler's library, so the
 call must happen in the one test worker that runs this file.
 """
+import importlib.util
 import os
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -88,3 +91,58 @@ def test_fused_capacity_peaks_at_32_chunks():
     """The largest compaction buffer the snapshot path asks for at 256
     KiB chunks is the one compiled above: 32 chunks = the 8 MiB budget."""
     assert ops.fused_capacity(10 ** 6, CB, 10 ** 6) == 32
+
+
+# --- names the trace reduction keys on -------------------------------------
+
+BENCH = Path(__file__).resolve().parents[1] / "bench"
+
+
+def _load(path: Path):
+    name = "bench_" + path.stem.replace(".", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = sys.modules[name] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("program,case", [
+    ("jit__fingerprint_impl", "fingerprint-bfloat16"),
+    ("jit__fused_capture_impl", "fused-bfloat16-capacity8")])
+def test_capture_kernels_keep_the_names_the_trace_reduction_reads(
+        one_chip, program, case):
+    """The device trace names each op by its HLO text; the reduction
+    (``bench/trace.py``) and ``capture_kernel_roofline`` find the capture
+    kernels by the names of their ops and programs. A rename would turn
+    the metric to null without an error."""
+    trace = _load(BENCH / "trace.py")
+    reader = _load(BENCH / "metrics" / "capture_kernel_roofline.py")
+    text = CASES[case](one_chip).compile().as_text()
+    assert text.startswith(f"HloModule {program},")
+    kernels = {k[0] for k in map(trace.kernel_call, text.splitlines()) if k}
+    assert kernels == {program[len("jit_"):]}
+    assert kernels <= set(reader.KERNELS)
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_engine_steps_keep_their_program_names(kind):
+    """The breakdown's device time per program reads the engine's steps
+    as ``jit_decode_fn`` and ``jit_prefill_fn``."""
+    from repro.configs import get_smoke_config
+    from repro.configs.base import ShapeConfig
+    from repro.launch.mesh import make_mesh
+    from repro.models import model as M
+    from repro.serving import engine as E
+
+    cfg = get_smoke_config("phi4-mini-3.8b")
+    mesh = make_mesh((1, 1), ("data", "model"))
+    shape = ShapeConfig(f"{kind}_s32_b2", 32, 2, kind)
+    build = E.jit_decode_step if kind == "decode" else E.jit_prefill
+    fn, _ = build(cfg, shape, mesh)
+    specs = E.serve_input_specs(cfg, shape)
+    params = M.init_abstract(cfg)
+    args = ((params, specs["cache"], specs["tokens"], specs["pos"])
+            if kind == "decode" else
+            (params, specs["tokens"], specs["cache"]))
+    text = fn.lower(*args).as_text()
+    assert text.startswith(f"module @jit_{kind}_fn ")
