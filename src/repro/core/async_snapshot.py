@@ -13,7 +13,11 @@ A snapshot moves through three phases:
             delta codec (core.delta / kernels.ckpt_codec): int8
             quantization for error-tolerant kinds, XOR against the
             previous snapshot's staging slot when delta chaining is on,
-            content-addressed chunking always.
+            content-addressed chunking always. The per-chunk store
+            transform (zlib, blake2b) of a multi-chunk part fans out over
+            a bounded chunk pool; the XOR, the blob-store calls and the
+            manifest stay on the encode thread, in chunk order, so the
+            blobs and manifests are those of a serial encode.
   commit    chunk blobs stream to the backend on a writer pool
             (``put_blob`` fan-out, bounded in-flight bytes); once every
             blob is durable the manifest is committed by the backend's
@@ -61,6 +65,7 @@ caller thread (fingerprint state is advanced at capture time).
 """
 from __future__ import annotations
 
+import os
 import threading
 import time
 import weakref
@@ -507,8 +512,12 @@ class AsyncSnapshotter:
         self._fp_invalid = False          # set by encode-thread failures
         self._fp_pool: Optional[ThreadPoolExecutor] = None
         self._fp_workers = 1
+        # half the host's CPUs transform chunks; the rest are left to the
+        # step loop, the runtime and the writers
+        self._chunk_workers = max(1, min(8, (os.cpu_count() or 1) // 2))
+        self._chunk_pool = ThreadPoolExecutor(
+            max_workers=self._chunk_workers, thread_name_prefix="snap-chunk")
         if self.sparse_capture:
-            import os
             self._fp_workers = min(4, os.cpu_count() or 1)
             if self._fp_workers > 1:
                 self._fp_pool = ThreadPoolExecutor(
@@ -537,6 +546,11 @@ class AsyncSnapshotter:
             "encode_commit_seconds": 0.0, "encode_cpu_seconds": 0.0,
             "encode_put_wait_seconds": 0.0, "commit_seconds": 0.0,
             "write_seconds": 0.0,
+            # chunk transforms run on the chunk pool, their CPU time
+            # summed over its workers, and the encode thread's waits for
+            # the oldest result (deltamod.ChunkPool)
+            "encode_pool_chunks": 0, "encode_pool_cpu_seconds": 0.0,
+            "encode_pool_wait_seconds": 0.0,
         }
 
     # --- capture (caller thread) ------------------------------------------
@@ -675,6 +689,7 @@ class AsyncSnapshotter:
                 "snapshot failed); this snapshot cannot be encoded")
 
         writer = _BlobWriter(self.backend, self._writer_pool)
+        pool = deltamod.ChunkPool(self._chunk_pool, 2 * self._chunk_workers)
         entries_manifest: Dict[str, Any] = {}
         written = logical = encoded = 0
         for name, leaves in cap.host_state.items():
@@ -705,7 +720,7 @@ class AsyncSnapshotter:
                             arr.dirty_bytes if arr.dirty_bytes is not None
                             else np.empty((0, arr.chunk_bytes), np.uint8),
                             prev_arr, writer.put, writer.has,
-                            compress=self.compress)
+                            compress=self.compress, pool=pool)
                         logical += arr.nbytes
                     else:
                         prev_arr = None
@@ -714,7 +729,7 @@ class AsyncSnapshotter:
                             prev_arr = base_state.get(name, {}).get(path)
                         m = deltamod.encode_leaf(
                             arr, writer.put, writer.has, codec=codec,
-                            prev=prev_arr, compress=self.compress)
+                            prev=prev_arr, compress=self.compress, pool=pool)
                         logical += arr.nbytes
                         if has_sparse:
                             # the old prev slot stays pinned as the mirror;
@@ -761,7 +776,7 @@ class AsyncSnapshotter:
             cap.sparse_committed = has_sparse
             self.backend.commit_manifest(cap.step, manifest)
         self._chain_len = self._chain_len + 1 if chain else 0
-        for k, v in writer.counts.items():
+        for k, v in {**writer.counts, **pool.counts}.items():
             self.stats[k] += v
         self.stats["encode_cpu_seconds"] += cpu
         self.stats["bytes_written"] += written
@@ -837,6 +852,7 @@ class AsyncSnapshotter:
             self.drain()
         finally:
             self._encode_pool.shutdown(wait=True)
+            self._chunk_pool.shutdown(wait=True)
             self._writer_pool.shutdown(wait=True)
             if self._fp_pool is not None:
                 self._fp_pool.shutdown(wait=True)
@@ -1034,7 +1050,6 @@ def materialize_manifest_chain(
     tasks = [(name, path) for name, e in final["entries"].items()
              if name not in skip for path in e["leaves"]]
     if workers is None:
-        import os
         workers = min(8, os.cpu_count() or 1)
     if workers > 1 and len(tasks) >= _PARALLEL_DECODE_MIN_LEAVES:
         with ThreadPoolExecutor(max_workers=workers,

@@ -21,8 +21,11 @@ Three leaf encodings, chosen per tensor by the snapshot pipeline:
 The encode API is *streaming*: ``encode_leaf`` walks a tensor one chunk
 at a time (no whole-tensor XOR materialization) and hands each chunk to a
 ``put_blob`` callable, which the async snapshot pipeline backs with a
-writer thread pool. ``decode_leaf`` inverts one link; chain walking lives
-in ``core.async_snapshot.materialize_manifest_chain``.
+writer thread pool. Given a ``ChunkPool``, each chunk's store transform
+(zlib, blake2b) runs on worker threads, at most the pool's window ahead
+of ``put_blob``; blobs and metas are those of the serial encode.
+``decode_leaf`` inverts one link; chain walking lives in
+``core.async_snapshot.materialize_manifest_chain``.
 
 Manifest leaf format (format 2) — format-1 metas (no "mode" key) are
 still decoded for old checkpoints:
@@ -46,12 +49,18 @@ chunks, so chain application cost also scales with the delta. Formats
 from __future__ import annotations
 
 import hashlib
+import time
 import zlib
-from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
+from collections import deque
+from concurrent.futures import Executor, Future
+from concurrent.futures import wait as futures_wait
+from typing import (Any, Callable, Deque, Dict, Iterable, Iterator, List,
+                    Optional, Tuple)
 
 import numpy as np
 
 from repro.api.errors import RestoreError, SnapshotError
+from repro.spans import add, span
 
 try:  # bfloat16 numpy interop (ships with jax)
     import ml_dtypes
@@ -128,9 +137,10 @@ def iter_chunk_views(arr: np.ndarray) -> Iterator[memoryview]:
 _PROBE_BYTES = 64 * 1024
 
 
-def _store_chunk(chunk: bytes, put_blob, has_blob,
-                 compress: bool) -> Tuple[str, str, int]:
-    """Store one chunk; returns (hash, enc, bytes_written)."""
+def transform_chunk(data, compress: bool) -> Tuple[str, str, bytes]:
+    """The store transform of one chunk: (hash, enc, payload). ``data``
+    is any contiguous byte buffer (a chunk view, an XOR delta row)."""
+    chunk = data.tobytes()
     enc = ENC_RAW
     if compress and len(chunk) > 0:
         # probe a prefix first: full-chunk zlib on incompressible float
@@ -142,11 +152,102 @@ def _store_chunk(chunk: bytes, put_blob, has_blob,
             packed = zlib.compress(chunk, _ZLIB_LEVEL)
             if len(packed) < len(chunk) * 9 // 10:
                 chunk, enc = packed, ENC_ZLIB
-    h = _hash(chunk)
-    if has_blob(h):
-        return h, enc, 0
-    put_blob(h, chunk)
-    return h, enc, len(chunk)
+    return _hash(chunk), enc, chunk
+
+
+# a part of fewer chunks than this transforms them on the encode thread:
+# a worker handoff buys nothing without a second chunk to overlap
+POOL_MIN_CHUNKS = 2
+
+
+class ChunkPool:
+    """One save's chunk transforms on a shared worker pool. zlib and
+    blake2b release the GIL on large buffers, so the transforms of
+    consecutive chunks run in parallel while the encode thread keeps
+    the XOR, the blob-store calls and the manifest, in chunk order.
+    At most ``window`` results are pending at a time.
+
+    ``counts``: ``encode_pool_chunks`` (transforms submitted),
+    ``encode_pool_cpu_seconds`` (the workers' ``time.thread_time()``
+    over them) and ``encode_pool_wait_seconds`` (the encode thread
+    blocked on the oldest result)."""
+
+    def __init__(self, executor: Executor, window: int) -> None:
+        self._executor = executor
+        self.window = window
+        self.counts = {"encode_pool_chunks": 0,
+                       "encode_pool_cpu_seconds": 0.0,
+                       "encode_pool_wait_seconds": 0.0}
+
+    def submit(self, data, compress: bool) -> Future:
+        add(self.counts, "encode_pool_chunks", 1)
+        return self._executor.submit(self._run, data, compress)
+
+    def _run(self, data, compress: bool) -> Tuple[str, str, bytes]:
+        cpu0 = time.thread_time()
+        try:
+            with span("ckpt.encode.chunk", bytes=data.nbytes):
+                return transform_chunk(data, compress)
+        finally:
+            add(self.counts, "encode_pool_cpu_seconds",
+                time.thread_time() - cpu0)
+
+    def result(self, fut: Future) -> Tuple[str, str, bytes]:
+        if fut.done():
+            return fut.result()
+        t0 = time.perf_counter()
+        try:
+            return fut.result()
+        finally:
+            add(self.counts, "encode_pool_wait_seconds",
+                time.perf_counter() - t0)
+
+
+def _store_chunks(datas: Iterable[Optional[Any]], put_blob, has_blob,
+                  compress: bool, pool: Optional[ChunkPool] = None,
+                  ) -> Tuple[List[Optional[Tuple[str, str]]], int]:
+    """Transform and store chunks; a None (an elided all-zero chunk)
+    stays None. Returns ([(hash, enc) | None, ...], bytes_written).
+
+    ``has_blob``/``put_blob`` run on the calling thread in chunk order
+    either way, so blobs, dedupe and the returned list are the same with
+    and without ``pool``; with it the transforms run ahead on its
+    workers, as far as its window."""
+    out: List[Optional[Tuple[str, str]]] = []
+    written = 0
+
+    def store(t: Optional[Tuple[str, str, bytes]]) -> None:
+        nonlocal written
+        if t is None:
+            out.append(None)
+            return
+        h, enc, payload = t
+        if not has_blob(h):
+            put_blob(h, payload)
+            written += len(payload)
+        out.append((h, enc))
+
+    if pool is None:
+        for d in datas:
+            store(None if d is None else transform_chunk(d, compress))
+        return out, written
+    pending: Deque[Optional[Future]] = deque()
+    try:
+        for d in datas:
+            while pending and (len(pending) >= pool.window
+                               or pending[0] is None or pending[0].done()):
+                f = pending.popleft()
+                store(None if f is None else pool.result(f))
+            pending.append(None if d is None else pool.submit(d, compress))
+        while pending:
+            f = pending.popleft()
+            store(None if f is None else pool.result(f))
+    finally:
+        # after a failure: no transform outlives the save that owns its
+        # buffer
+        left = [f for f in pending if f is not None and not f.cancel()]
+        futures_wait(left)
+    return out, written
 
 
 def _load_chunk(entry: Optional[str], enc: str, length: int,
@@ -176,12 +277,11 @@ def _use_device_xor() -> bool:
 
 def _encode_part(p: np.ndarray, put_blob, has_blob, *,
                  prev: Optional[np.ndarray] = None,
-                 compress: bool = True) -> Tuple[Dict[str, Any], int]:
+                 compress: bool = True,
+                 pool: Optional[ChunkPool] = None,
+                 ) -> Tuple[Dict[str, Any], int]:
     """Chunk one part array; XOR against `prev` chunk-by-chunk when given
     (streaming — never materializes the full delta)."""
-    chunks: List[Optional[str]] = []
-    encs: List[str] = []
-    written = 0
     # the device-vs-host XOR decision is per-part, not per-chunk: the
     # backend probe is hoisted out of the chunk loop
     if prev is not None and _use_device_xor():
@@ -193,25 +293,24 @@ def _encode_part(p: np.ndarray, put_blob, has_blob, *,
             return np.bitwise_xor(a, b)
     else:
         xor = np.bitwise_xor
-    prev_iter = iter_chunk_views(p if prev is None else prev)
-    for view in iter_chunk_views(p):
-        if prev is not None:
-            pview = next(prev_iter)
+
+    def datas() -> Iterator[Optional[Any]]:
+        if prev is None:
+            yield from iter_chunk_views(p)
+            return
+        for view, pview in zip(iter_chunk_views(p), iter_chunk_views(prev)):
             delta = xor(np.frombuffer(view, np.uint8),
                         np.frombuffer(pview, np.uint8))
-            if not delta.any():
-                chunks.append(None)   # unchanged region: costs nothing
-                encs.append(ENC_RAW)
-                continue
-            data = delta.tobytes()
-        else:
-            data = view.tobytes()
-        h, enc, w = _store_chunk(data, put_blob, has_blob, compress)
-        chunks.append(h)
-        encs.append(enc)
-        written += w
+            # an unchanged region costs nothing
+            yield delta if delta.any() else None
+
+    if -(-p.nbytes // CHUNK_BYTES) < POOL_MIN_CHUNKS:
+        pool = None
+    stored, written = _store_chunks(datas(), put_blob, has_blob, compress,
+                                    pool)
     meta = {"dtype": str(p.dtype), "shape": list(p.shape),
-            "chunks": chunks, "enc": encs}
+            "chunks": [None if s is None else s[0] for s in stored],
+            "enc": [ENC_RAW if s is None else s[1] for s in stored]}
     return meta, written
 
 
@@ -256,6 +355,7 @@ def encode_leaf_sparse(
     *,
     compress: bool = True,
     patch_prev: bool = True,
+    pool: Optional[ChunkPool] = None,
 ) -> Dict[str, Any]:
     """Encode one leaf from a sparse dirty-chunk capture.
 
@@ -269,13 +369,16 @@ def encode_leaf_sparse(
     PLACE chunk by chunk, so after the leaf is encoded the buffer holds
     the *current* snapshot's bytes — the pipeline keeps one full host
     mirror alive instead of two.
+
+    ``pool`` transforms the dirty chunks on its workers when there are
+    at least ``POOL_MIN_CHUNKS`` of them; the blobs and the meta are the
+    same as without it.
     """
     dtype = np.dtype(dtype)
     total = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
     prev_b = np.ascontiguousarray(prev).reshape(-1).view(np.uint8)
     assert prev_b.size == total, (prev_b.size, total)
-    dirty: List[List[Any]] = []
-    written = encoded = 0
+    encoded = 0
     idxs = np.asarray(dirty_idx, np.int64)
     if idxs.size and np.any(np.diff(idxs) < 0):  # capture emits sorted;
         order = np.argsort(idxs)                 # guard other callers
@@ -289,6 +392,7 @@ def encode_leaf_sparse(
     # largest index) is last and the manifest order is unchanged.
     n_full = total // chunk_bytes
     k_full = int(np.searchsorted(idxs, n_full))
+    todo: List[Tuple[int, np.ndarray]] = []   # (chunk index, XOR delta)
     if k_full:
         grid = prev_b[:n_full * chunk_bytes].reshape(n_full, chunk_bytes)
         fi = idxs[:k_full]
@@ -298,11 +402,7 @@ def encode_leaf_sparse(
         if patch_prev:
             grid[fi] = cur_rows  # in-place mirror advance, one scatter
         encoded += k_full * chunk_bytes
-        for j in np.nonzero(changed)[0]:
-            h, enc, w = _store_chunk(deltas[j].tobytes(), put_blob,
-                                     has_blob, compress)
-            dirty.append([int(idxs[j]), h, enc])
-            written += w
+        todo += [(int(idxs[j]), deltas[j]) for j in np.nonzero(changed)[0]]
     for j in range(k_full, idxs.size):  # partial tail chunk
         off = int(idxs[j]) * chunk_bytes
         ln = total - off
@@ -312,12 +412,12 @@ def encode_leaf_sparse(
         encoded += ln
         if patch_prev:
             pv[:] = cur
-        if not delta.any():
-            continue  # conservative dirty mark; nothing actually changed
-        h, enc, w = _store_chunk(delta.tobytes(), put_blob, has_blob,
-                                 compress)
-        dirty.append([int(idxs[j]), h, enc])
-        written += w
+        if delta.any():  # else a conservative dirty mark: nothing changed
+            todo.append((int(idxs[j]), delta))
+    stored, written = _store_chunks(
+        (d for _, d in todo), put_blob, has_blob, compress,
+        pool if len(todo) >= POOL_MIN_CHUNKS else None)
+    dirty = [[i, h, enc] for (i, _), (h, enc) in zip(todo, stored)]
     return {
         "shape": list(shape),
         "dtype": str(dtype),
@@ -363,13 +463,16 @@ def encode_leaf(
     codec: Optional[str] = None,
     prev: Optional[np.ndarray] = None,
     compress: bool = True,
+    pool: Optional[ChunkPool] = None,
 ) -> Dict[str, Any]:
     """Encode one tensor into blobs + leaf manifest.
 
     ``prev`` (same shape/dtype tensor from the previous snapshot) selects
     xor mode; ``codec`` selects the lossy codec (mutually exclusive with
     xor — quantized entries rely on chunk dedup instead, so requantization
-    noise never accumulates along a chain)."""
+    noise never accumulates along a chain). ``pool`` transforms the
+    chunks of each part of at least ``POOL_MIN_CHUNKS`` chunks on its
+    workers; the blobs and the meta are the same as without it."""
     arr = np.asarray(arr)
     meta: Dict[str, Any] = {
         "shape": list(arr.shape),
@@ -382,19 +485,21 @@ def encode_leaf(
         meta["mode"] = "codec"
         meta["codec"] = codec
         for pname, p in CODECS[codec][0](arr).items():
-            pmeta, w = _encode_part(p, put_blob, has_blob, compress=compress)
+            pmeta, w = _encode_part(p, put_blob, has_blob, compress=compress,
+                                    pool=pool)
             meta["parts"][pname] = pmeta
             written += w
     elif (prev is not None and prev.shape == arr.shape
           and prev.dtype == arr.dtype):
         meta["mode"] = "xor"
         pmeta, w = _encode_part(arr, put_blob, has_blob, prev=prev,
-                                compress=compress)
+                                compress=compress, pool=pool)
         meta["parts"]["raw"] = pmeta
         written += w
     else:
         meta["mode"] = "full"
-        pmeta, w = _encode_part(arr, put_blob, has_blob, compress=compress)
+        pmeta, w = _encode_part(arr, put_blob, has_blob, compress=compress,
+                                pool=pool)
         meta["parts"]["raw"] = pmeta
         written += w
     meta["bytes_written"] = written

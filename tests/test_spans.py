@@ -24,7 +24,8 @@ OLD_STATS = {"saves", "skipped", "failed", "bytes_written", "bytes_logical",
              "host_fp_leaves", "capture_seconds", "encode_commit_seconds"}
 NEW_STATS = {"d2h_bytes", "d2h_seconds", "slot_wait_seconds",
              "encode_cpu_seconds", "encode_put_wait_seconds",
-             "commit_seconds", "write_seconds"}
+             "commit_seconds", "write_seconds", "encode_pool_chunks",
+             "encode_pool_cpu_seconds", "encode_pool_wait_seconds"}
 
 
 def test_span_adds_its_seconds_into_the_callers_dict():
