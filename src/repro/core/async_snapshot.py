@@ -69,7 +69,7 @@ import os
 import threading
 import time
 import weakref
-from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import Executor, Future, ThreadPoolExecutor
 from concurrent.futures import TimeoutError as _FuturesTimeout
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
@@ -991,30 +991,42 @@ def leaf_chain_start(manifests: List[Dict[str, Any]], name: str,
 
 def _decode_chain_leaf(manifests: List[Dict[str, Any]], backend,
                        name: str, path: str,
-                       timings: Optional[Dict[str, Any]] = None
-                       ) -> np.ndarray:
+                       timings: Optional[Dict[str, Any]] = None,
+                       executor: Optional[Executor] = None) -> np.ndarray:
     """Decode one leaf of the final manifest from the start of its xor
-    run (``leaf_chain_start``) forward, XOR-applying each link. With
-    ``timings``, the leaf's blob reads add to ``fetch_s`` and the rest
-    of its decode (zlib, XOR, sparse patch) to ``decode_s``."""
+    run (``leaf_chain_start``) forward, XOR-applying each link into the
+    previous link's value, which nothing else holds. With ``executor``,
+    the chunks of each large part decode on it (``delta.DecodePool``):
+    this thread submits them, waits, and walks on. With ``timings``, the
+    leaf's blob reads add to ``fetch_s`` and the rest of its work (zlib,
+    XOR, sparse patch), on this thread and in its chunk tasks but never
+    the wait for them, to ``decode_s``; ``decode_pool_chunks`` and
+    ``decode_pool_wait_s`` count its chunk tasks and that wait."""
     i = leaf_chain_start(manifests, name, path)
+    pool = None if executor is None else deltamod.DecodePool(executor)
     get_blob = backend.get_blob
-    fetch_s = 0.0
+    reads: Dict[str, float] = {}
     if timings is not None:
         def get_blob(blob: str) -> bytes:
-            nonlocal fetch_s
             with span("ckpt.restore.fetch") as sp:
                 data = backend.get_blob(blob)
-            fetch_s += sp.seconds
+            add(reads, "fetch_s", sp.seconds)  # this thread or a chunk task
             return data
     val: Optional[np.ndarray] = None
     with span("ckpt.restore.decode", path=f"{name}:{path}") as sp:
         for m in manifests[i:]:
             val = deltamod.decode_leaf(
-                m["entries"][name]["leaves"][path], get_blob, prev=val)
+                m["entries"][name]["leaves"][path], get_blob, prev=val,
+                pool=pool, patch_prev=True)
     if timings is not None:
+        pc = pool.counts if pool is not None else {}
+        fetch_s = reads.get("fetch_s", 0.0)
+        wait_s = pc.get("decode_pool_wait_s", 0.0)
         add(timings, "fetch_s", fetch_s)
-        add(timings, "decode_s", sp.seconds - fetch_s)
+        add(timings, "decode_s", sp.seconds - wait_s
+            + pc.get("decode_pool_task_s", 0.0) - fetch_s)
+        add(timings, "decode_pool_chunks", pc.get("decode_pool_chunks", 0))
+        add(timings, "decode_pool_wait_s", wait_s)
     return val
 
 
@@ -1028,21 +1040,26 @@ def materialize_manifest_chain(
     skip_entries=(), timings: Optional[Dict[str, Any]] = None,
 ) -> Tuple[Dict[str, Any], Dict[str, Dict[str, np.ndarray]]]:
     """Delta chain -> full state. Each leaf decodes independently (its
-    own xor-run walk), so leaves fan out across a worker pool — restore
-    latency is bounded by the largest leaf's chain, not the sum of all
-    of them. Leaves that exist only in intermediate manifests — or are
-    non-xor there — are never decoded, so restore cost per leaf stays
-    O(xor-run length), not O(chain length). Sparse (format-3) links
-    apply as copy + dirty-chunk patch rather than a full-buffer XOR, so
-    chain application also scales with what each link changed.
+    own xor-run walk), so leaves fan out across a worker pool, and the
+    chunks of each large part fan out across a chunk pool — restore
+    latency is bounded by the largest leaf's chunks over the pool, not
+    the sum of all of them. Leaves that exist only in intermediate
+    manifests — or are non-xor there — are never decoded, so restore
+    cost per leaf stays O(xor-run length), not O(chain length). Sparse
+    (format-3) links patch the dirty chunks of the previous link's value
+    in place rather than XOR a full buffer, so chain application also
+    scales with what each link changed.
 
-    ``workers``: decode pool size; default scales with the host, 1
-    forces the serial path (both orders produce identical arrays).
+    ``workers``: the width of the leaf pool and of the chunk pool that
+    the leaves' large parts decode on; default scales with the host, 1
+    forces the serial path (every path produces identical arrays).
     ``skip_entries``: entry names to leave undecoded (absent from the
     result) — a caller that rebuilds an entry from scratch, like the
     serving engine re-slotting its KV cache, shouldn't pay its chain.
-    ``timings``: receives ``fetch_s`` and ``decode_s``, each summed over
-    the leaves (so over the workers, not wall time)."""
+    ``timings``: receives ``fetch_s`` and ``decode_s``, each the work
+    summed over the leaves and their chunk tasks (so over threads, not
+    wall time), ``decode_pool_chunks`` and ``decode_pool_wait_s`` (the
+    leaves' threads blocked on their chunk tasks, summed over them)."""
     manifests = [backend.get_manifest(s)
                  for s in manifest_chain_steps(backend, step)]
     final = manifests[-1]
@@ -1051,15 +1068,27 @@ def materialize_manifest_chain(
              if name not in skip for path in e["leaves"]]
     if workers is None:
         workers = min(8, os.cpu_count() or 1)
-    if workers > 1 and len(tasks) >= _PARALLEL_DECODE_MIN_LEAVES:
-        with ThreadPoolExecutor(max_workers=workers,
-                                thread_name_prefix="chain-decode") as pool:
-            vals = list(pool.map(
-                lambda t: _decode_chain_leaf(manifests, backend, *t,
-                                             timings), tasks))
-    else:
+    if timings is not None:
+        timings.setdefault("decode_pool_chunks", 0)
+        timings.setdefault("decode_pool_wait_s", 0.0)
+    if workers <= 1:
         vals = [_decode_chain_leaf(manifests, backend, name, path, timings)
                 for name, path in tasks]
+    else:
+        # a separate pool for the chunks: a leaf thread blocked on its
+        # chunk tasks never holds a worker that they need
+        with ThreadPoolExecutor(max_workers=workers,
+                                thread_name_prefix="chain-chunk") as chunks:
+            def decode(t: Tuple[str, str]) -> np.ndarray:
+                return _decode_chain_leaf(manifests, backend, *t, timings,
+                                          chunks)
+            if len(tasks) >= _PARALLEL_DECODE_MIN_LEAVES:
+                with ThreadPoolExecutor(
+                        max_workers=workers,
+                        thread_name_prefix="chain-decode") as pool:
+                    vals = list(pool.map(decode, tasks))
+            else:
+                vals = [decode(t) for t in tasks]
     entries: Dict[str, Dict[str, np.ndarray]] = {}
     for (name, path), val in zip(tasks, vals):
         entries.setdefault(name, {})[path] = val

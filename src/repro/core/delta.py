@@ -24,7 +24,8 @@ at a time (no whole-tensor XOR materialization) and hands each chunk to a
 writer thread pool. Given a ``ChunkPool``, each chunk's store transform
 (zlib, blake2b) runs on worker threads, at most the pool's window ahead
 of ``put_blob``; blobs and metas are those of the serial encode.
-``decode_leaf`` inverts one link; chain walking lives in
+``decode_leaf`` inverts one link, given a ``DecodePool`` with each
+chunk's read, inflate and XOR on worker threads; chain walking lives in
 ``core.async_snapshot.materialize_manifest_chain``.
 
 Manifest leaf format (format 2) — format-1 metas (no "mode" key) are
@@ -42,8 +43,8 @@ placeholders, the part records only the chunks that changed —
     {"dtype", "shape", "chunk_bytes": int, "n_chunks": int,
      "dirty": [[chunk_idx, hash, enc], ...]}
 
-Decoding a sparse part copies the base value and XOR-patches the dirty
-chunks, so chain application cost also scales with the delta. Formats
+Decoding a sparse part XOR-patches the dirty chunks of (a copy of) the
+base value, so chain application cost also scales with the delta. Formats
 1-3 are all decoded by this module (compatibility matrix in README).
 """
 from __future__ import annotations
@@ -52,7 +53,7 @@ import hashlib
 import time
 import zlib
 from collections import deque
-from concurrent.futures import Executor, Future
+from concurrent.futures import FIRST_EXCEPTION, Executor, Future
 from concurrent.futures import wait as futures_wait
 from typing import (Any, Callable, Deque, Dict, Iterable, Iterator, List,
                     Optional, Tuple)
@@ -155,8 +156,9 @@ def transform_chunk(data, compress: bool) -> Tuple[str, str, bytes]:
     return _hash(chunk), enc, chunk
 
 
-# a part of fewer chunks than this transforms them on the encode thread:
-# a worker handoff buys nothing without a second chunk to overlap
+# a part of fewer chunks than this transforms (or decodes) them on the
+# calling thread: a worker handoff buys nothing without a second chunk
+# to overlap
 POOL_MIN_CHUNKS = 2
 
 
@@ -250,14 +252,81 @@ def _store_chunks(datas: Iterable[Optional[Any]], put_blob, has_blob,
     return out, written
 
 
-def _load_chunk(entry: Optional[str], enc: str, length: int,
-                get_blob) -> bytes:
+def _flat_bytes(a: np.ndarray) -> np.ndarray:
+    return np.ascontiguousarray(a).reshape(-1).view(np.uint8)
+
+
+def _decode_chunk(get_blob, entry: Optional[str], enc: str,
+                  dst: np.ndarray, against: Optional[np.ndarray]) -> None:
+    """Decode one stored chunk into ``dst``, its slot of the part's
+    output: the chunk's bytes, or those XORed with ``against`` (the base
+    value's slice, or ``dst`` itself when it already holds the base)."""
     if entry is None:  # elided all-zero chunk
-        return bytes(length)
+        if against is None:
+            dst[:] = 0
+        elif against is not dst:
+            dst[:] = against
+        return
     data = get_blob(entry)
     if enc == ENC_ZLIB:
         data = zlib.decompress(data)
-    return data
+    if len(data) != dst.nbytes:
+        raise RestoreError(f"chunk {entry} decodes to {len(data)} bytes; "
+                           f"its slot holds {dst.nbytes}")
+    buf = np.frombuffer(data, np.uint8)
+    if against is None:
+        dst[:] = buf
+    else:
+        np.bitwise_xor(buf, against, out=dst)
+
+
+class DecodePool:
+    """One leaf's chunk decodes on a shared worker pool. The blob reads,
+    zlib and numpy's copy and XOR release the GIL, so the chunks of a
+    large part decode in parallel; each writes its own slice of the
+    part's output, so they need no lock. The leaf's thread only submits
+    a part's chunks, waits for them and walks on to the next link.
+
+    ``counts``: ``decode_pool_chunks`` (chunk tasks run),
+    ``decode_pool_wait_s`` (the leaf's thread blocked on them) and
+    ``decode_pool_task_s`` (the tasks' own seconds, reads included)."""
+
+    def __init__(self, executor: Executor) -> None:
+        self._executor = executor
+        self.counts = {"decode_pool_chunks": 0, "decode_pool_wait_s": 0.0,
+                       "decode_pool_task_s": 0.0}
+
+    def _run(self, get_blob, entry, enc, dst, against) -> None:
+        with span("ckpt.restore.chunk", bytes=dst.nbytes) as sp:
+            _decode_chunk(get_blob, entry, enc, dst, against)
+        add(self.counts, "decode_pool_task_s", sp.seconds)
+
+    def run(self, get_blob, jobs: List[Tuple]) -> None:
+        """Decode every ``(entry, enc, dst, against)`` job on the pool and
+        return when all are done. A failure is raised once every other
+        job has been cancelled or has finished: none outlives the call."""
+        futs: List[Future] = []
+        try:
+            for job in jobs:
+                futs.append(self._executor.submit(self._run, get_blob, *job))
+            add(self.counts, "decode_pool_chunks", len(futs))
+            t0 = time.perf_counter()
+            futures_wait(futs, return_when=FIRST_EXCEPTION)
+            add(self.counts, "decode_pool_wait_s", time.perf_counter() - t0)
+            for f in futs:
+                if f.done() and f.exception() is not None:
+                    f.result()
+        finally:
+            futures_wait([f for f in futs if not f.cancel()])
+
+
+def _decode_chunks(jobs: List[Tuple], get_blob,
+                   pool: Optional[DecodePool]) -> None:
+    if pool is not None and len(jobs) >= POOL_MIN_CHUNKS:
+        pool.run(get_blob, jobs)
+    else:
+        for job in jobs:
+            _decode_chunk(get_blob, *job)
 
 
 _DEVICE_XOR_MIN_BYTES = 1 << 20
@@ -315,26 +384,35 @@ def _encode_part(p: np.ndarray, put_blob, has_blob, *,
 
 
 def _decode_part(pmeta: Dict[str, Any], get_blob,
-                 prev: Optional[np.ndarray] = None) -> np.ndarray:
+                 prev: Optional[np.ndarray] = None,
+                 pool: Optional[DecodePool] = None,
+                 patch_prev: bool = False) -> np.ndarray:
     if "dirty" in pmeta:  # format-3 sparse dirty-chunk part
         if prev is None:
             raise RestoreError("sparse xor part needs its base-step value")
-        return _decode_part_sparse(pmeta, get_blob, prev)
+        return _decode_part_sparse(pmeta, get_blob, prev, pool, patch_prev)
     dt = _np_dtype(pmeta["dtype"])
     shape = pmeta["shape"]
     total = int(np.prod(shape, dtype=np.int64)) * dt.itemsize
-    encs = pmeta.get("enc") or [ENC_RAW] * len(pmeta["chunks"])
-    out = np.empty(total, np.uint8)
-    off = 0
-    for entry, enc in zip(pmeta["chunks"], encs):
-        length = min(CHUNK_BYTES, total - off) if total else 0
-        data = _load_chunk(entry, enc, length, get_blob)
-        buf = np.frombuffer(data, np.uint8)
-        out[off:off + len(buf)] = buf
-        off += len(buf)
-    if prev is not None:
-        pb = np.ascontiguousarray(prev).reshape(-1).view(np.uint8)
-        np.bitwise_xor(out, pb, out=out)
+    chunks = pmeta["chunks"]
+    encs = pmeta.get("enc") or [ENC_RAW] * len(chunks)
+    if len(chunks) != max(1, -(-total // CHUNK_BYTES)):
+        raise RestoreError(f"a part of {total} bytes lists {len(chunks)} "
+                           f"chunks of {CHUNK_BYTES}")
+    base = None if prev is None else _flat_bytes(prev)
+    if base is not None and base.size != total:
+        raise RestoreError(f"xor base holds {base.size} bytes, the part "
+                           f"{total}")
+    out = base if patch_prev and base is not None \
+        else np.empty(total, np.uint8)
+    jobs = []
+    for i, (entry, enc) in enumerate(zip(chunks, encs)):
+        sl = slice(i * CHUNK_BYTES, (i + 1) * CHUNK_BYTES)
+        dst = out[sl]
+        jobs.append((entry, enc, dst,
+                     None if base is None else dst if out is base
+                     else base[sl]))
+    _decode_chunks(jobs, get_blob, pool)
     return out.view(dt).reshape(shape)
 
 
@@ -433,21 +511,34 @@ def encode_leaf_sparse(
 
 
 def _decode_part_sparse(pmeta: Dict[str, Any], get_blob,
-                        prev: np.ndarray) -> np.ndarray:
-    """Sparse chain link: copy the base value and XOR-patch only the
-    dirty chunks — chain application cost scales with the delta."""
+                        prev: np.ndarray,
+                        pool: Optional[DecodePool] = None,
+                        patch_prev: bool = False) -> np.ndarray:
+    """Sparse chain link: XOR-patch only the dirty chunks of the base
+    value (a copy of it, or ``prev`` itself with ``patch_prev``), so
+    chain application cost scales with the delta."""
     dt = _np_dtype(pmeta["dtype"])
     shape = pmeta["shape"]
     cb = pmeta["chunk_bytes"]
     total = int(np.prod(shape, dtype=np.int64)) * dt.itemsize
-    out = np.ascontiguousarray(prev).reshape(-1).view(np.uint8).copy()
-    assert out.size == total, (out.size, total)
+    out = _flat_bytes(prev)
+    if out.size != total:
+        raise RestoreError(f"xor base holds {out.size} bytes, the part "
+                           f"{total}")
+    if not patch_prev:
+        out = out.copy()
+    n_chunks = -(-total // cb)
+    jobs = []
+    last = -1
     for idx, entry, enc in pmeta["dirty"]:
-        off = idx * cb
-        ln = min(cb, total - off)
-        data = _load_chunk(entry, enc, ln, get_blob)
-        np.bitwise_xor(out[off:off + ln], np.frombuffer(data, np.uint8),
-                       out=out[off:off + ln])
+        # ascending and in range, so no two jobs write one slice
+        if not last < idx < n_chunks:
+            raise RestoreError(f"sparse part: dirty chunk {idx} after "
+                               f"{last}, of {n_chunks}")
+        last = idx
+        dst = out[idx * cb:(idx + 1) * cb]
+        jobs.append((entry, enc, dst, dst))
+    _decode_chunks(jobs, get_blob, pool)
     return out.view(dt).reshape(shape)
 
 
@@ -511,9 +602,16 @@ def encode_leaf(
 
 def decode_leaf(meta: Dict[str, Any],
                 get_blob: Callable[[str], bytes],
-                prev: Optional[np.ndarray] = None) -> np.ndarray:
+                prev: Optional[np.ndarray] = None, *,
+                pool: Optional[DecodePool] = None,
+                patch_prev: bool = False) -> np.ndarray:
     """Decode one leaf. xor-mode leaves need ``prev`` — the decoded value
-    of the same leaf at the manifest's base step."""
+    of the same leaf at the manifest's base step. ``pool`` decodes the
+    chunks of each part of at least ``POOL_MIN_CHUNKS`` chunks (dirty
+    chunks, for a sparse part) on its workers; the array is the same as
+    without it. ``patch_prev``: an xor leaf writes its value into
+    ``prev``'s buffer instead of a new one, for a caller that owns
+    ``prev`` and drops it."""
     dtype = _np_dtype(meta["dtype"])
     shape = tuple(meta["shape"])
     mode = meta.get("mode")
@@ -522,9 +620,9 @@ def decode_leaf(meta: Dict[str, Any],
     if mode == "xor":
         if prev is None:
             raise RestoreError("xor leaf needs its base-step value")
-        return _decode_part(meta["parts"]["raw"], get_blob,
-                            prev=prev).reshape(shape)
-    parts = {pname: _decode_part(pmeta, get_blob)
+        return _decode_part(meta["parts"]["raw"], get_blob, prev=prev,
+                            pool=pool, patch_prev=patch_prev).reshape(shape)
+    parts = {pname: _decode_part(pmeta, get_blob, pool=pool)
              for pname, pmeta in meta["parts"].items()}
     if mode == "codec":
         return CODECS[meta["codec"]][1](parts, dtype, shape)

@@ -379,6 +379,8 @@ class StreamingMaterializer:
         name, path = key
         t0 = time.monotonic()
         try:
+            # no chunk executor: each leaf decodes whole on one of this
+            # materializer's decode threads, as its blobs land
             val = _decode_chain_leaf(self.manifests, self._view, name,
                                      path)
         except Exception as e:
