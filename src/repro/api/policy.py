@@ -46,14 +46,6 @@ class Policy:
     ``replicate``  peer replication default for store specs that
                    support it (None = the spec decides).
     ``codecs``     entry kind -> codec name (e.g. {"opt_state": "int8"}).
-    ``streaming_restore`` restore-side default: stream the payload
-                   (return at hot-tier-decoded, cold entries page in on
-                   first touch) instead of materializing it as one
-                   barrier. Bit-identical either way; an explicit
-                   ``streaming=`` at the restore call wins.
-    ``lazy_kinds`` entry kinds the streaming restore defers to the cold
-                   tier (None = the streaming default: optimizer
-                   moments + KV cache).
     ``drain_deadline_s`` planned-move budget: the worst per-batch
                    blackout a ``CheckpointSession.migrate`` /
                    ``FleetRouter`` drain may cost before the move is
@@ -79,8 +71,6 @@ class Policy:
     async_save: bool = True
     replicate: Optional[bool] = None
     codecs: Mapping[str, str] = field(default_factory=dict)
-    streaming_restore: bool = False
-    lazy_kinds: Optional[tuple] = None
     drain_deadline_s: Optional[float] = None
     migrate_batch: Optional[int] = None
 
@@ -118,21 +108,6 @@ class Policy:
                 f"{'/'.join(sparse_knobs)} set with sparse=False: the "
                 "dirty-chunk knobs have no effect — enable sparse or "
                 "drop them")
-        if self.lazy_kinds is not None:
-            if isinstance(self.lazy_kinds, str) \
-                    or not all(isinstance(k, str) for k in self.lazy_kinds):
-                raise PolicyError(
-                    f"lazy_kinds={self.lazy_kinds!r} must be a sequence "
-                    "of entry-kind names (e.g. ('opt_state', 'cache')), "
-                    "or None for the streaming default")
-            object.__setattr__(self, "lazy_kinds", tuple(self.lazy_kinds))
-            if not self.streaming_restore:
-                raise PolicyError(
-                    f"lazy_kinds={self.lazy_kinds!r} set with "
-                    "streaming_restore=False: the cold tier only exists "
-                    "under a streaming restore — enable it or drop the "
-                    "knob (a per-call restore(streaming=True) uses the "
-                    "streaming default tiers)")
         if self.drain_deadline_s is not None and self.drain_deadline_s <= 0:
             raise PolicyError(
                 f"drain_deadline_s={self.drain_deadline_s}: the planned-"

@@ -181,8 +181,7 @@ def _sharded_backend(path: str, *, hosts="4", replicate="0", writers="4",
 def _cached_backend(path: str, *, over="", fsync="0"):
     """Local read-through blob cache over any other registered store:
     ``cached:/ssd-cache?over=sharded:/remote?hosts=4``. Reads hit the
-    local tier first and warm it on a miss; streaming restore fetches
-    from both tiers and primes the cache as it goes."""
+    local tier first and warm it on a miss."""
     from repro.core.backends.cached import CachedBackend
     if not over:
         raise PolicyError(

@@ -164,8 +164,6 @@ class CheckpointSession:
                 mesh_factory: Optional[Callable] = None,
                 rewrite_op: Optional[Callable] = None,
                 workers: Optional[int] = None,
-                decode_workers: Optional[int] = None,
-                streaming: Optional[bool] = None,
                 **app_kwargs: Any) -> Any:
         """Rebuild and attach the checkpointed app.
 
@@ -176,21 +174,8 @@ class CheckpointSession:
         ``n_slots=`` for the serving engine). ``expect_kind`` guards a
         caller that only handles one workload.
 
-        ``workers`` sizes the restore's fetch/decode pools, threaded
-        through the incarnation to ``CheckpointManager.restore``
-        (``decode_workers`` is the older spelling of the same knob).
-        ``streaming`` streams the payload — the app comes back once the
-        hot tier is decoded and cold entries page in on first touch —
-        with None deferring to ``policy.streaming_restore``. Streaming
-        and eager restores are bit-identical."""
-        if workers is not None and decode_workers is not None \
-                and workers != decode_workers:
-            raise PolicyError(
-                f"workers={workers} and decode_workers={decode_workers} "
-                "are the same knob spelled twice; pass one")
-        workers = workers if workers is not None else decode_workers
-        if streaming is None:
-            streaming = self.policy.streaming_restore
+        ``workers`` sizes the restore's decode pools, threaded through
+        the incarnation to ``CheckpointManager.restore``."""
         if step in (None, "latest"):
             resolved = self.manager.resolve_step(None)
         else:
@@ -203,9 +188,7 @@ class CheckpointSession:
         ctx = RestoreContext(self.manager, resolved, job,
                              mesh_factory=mesh_factory,
                              rewrite_op=rewrite_op,
-                             decode_workers=workers,
-                             streaming=bool(streaming),
-                             lazy_kinds=self.policy.lazy_kinds)
+                             workers=workers)
         return self.attach(binder(ctx, **app_kwargs))
 
     # --- live migration ------------------------------------------------
@@ -214,8 +197,7 @@ class CheckpointSession:
                 include_queue: bool = False,
                 via: Optional[str] = None,
                 batch: Optional[int] = None,
-                deadline_s: Optional[float] = None,
-                streaming: bool = True):
+                deadline_s: Optional[float] = None):
         """Live-migrate this session's serving app's sessions onto
         another engine, through the C/R protocol: the chosen slots
         freeze, snapshot as a ``SessionBundle`` on a *move channel* (a
@@ -252,8 +234,7 @@ class CheckpointSession:
             include_queue=include_queue,
             batch=batch if batch is not None else self.policy.migrate_batch,
             deadline_s=deadline_s if deadline_s is not None
-            else self.policy.drain_deadline_s,
-            streaming=streaming)
+            else self.policy.drain_deadline_s)
 
     # --- supervision ---------------------------------------------------
 

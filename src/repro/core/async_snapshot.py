@@ -975,9 +975,7 @@ def leaf_chain_start(manifests: List[Dict[str, Any]], name: str,
     introduced mid-chain bounds the walk — the predecessor manifest
     simply doesn't carry it — so the run starts at the introduction
     instead of raising KeyError. Every manifest in ``[start:]`` is
-    guaranteed to carry the leaf; this is the single definition of a
-    leaf's chain shared by the eager decoder and the streaming planner
-    (which is what makes their blob plans identical by construction)."""
+    guaranteed to carry the leaf."""
     i = len(manifests) - 1
     while i > 0 and (manifests[i]["entries"][name]["leaves"][path]
                      .get("mode") == "xor"):

@@ -18,17 +18,13 @@ Division of labor:
   inner store — publication/visibility must have exactly one source of
   truth, and a manifest read is tiny next to the blobs it references.
 * **replication machinery** sees through the front via the ``inner``
-  attribute (the replica-scan CLI unwraps it), and the streaming
-  restore's fetch fan-out gets both tiers as independent hedgeable
-  sources from ``blob_sources`` below — a fetch served by the remote
-  store still warms the cache, which is how a streaming restore doubles
-  as a cache-priming pass.
+  attribute (the replica-scan CLI unwraps it).
 """
 from __future__ import annotations
 
 import threading
 from pathlib import Path
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Dict, List
 
 from repro.core.backends.base import (CheckpointBackend, clean_tmp_under,
                                       write_atomic)
@@ -83,32 +79,6 @@ class CachedBackend(CheckpointBackend):
 
     def has_blob(self, name: str) -> bool:
         return self._cache_path(name).exists() or self.inner.has_blob(name)
-
-    def blob_sources(self, name: str) -> List[Tuple[str, Callable[[], bytes]]]:
-        """Both tiers as independent fetch sources for the streaming
-        restore: the local cache (preferred; raises on a miss so the
-        fetcher falls to the next source immediately) and every source
-        of the inner store, each wrapped to warm the cache on the way
-        through. Hedging a slow remote read against the cache is a
-        no-op on a cold cache and a free win on a warm one."""
-        from repro.core.replication import blob_sources as inner_sources
-
-        def read_cache() -> bytes:
-            data = self._cache_path(name).read_bytes()
-            with self._lock:
-                self.stats["hits"] += 1
-            return data
-
-        out: List[Tuple[str, Callable[[], bytes]]] = [("cache", read_cache)]
-        for label, read in inner_sources(self.inner, name):
-
-            def read_and_warm(r=read) -> bytes:
-                data = r()
-                self._warm(name, data)
-                return data
-
-            out.append((label, read_and_warm))
-        return out
 
     # --- everything with one source of truth delegates -----------------
 

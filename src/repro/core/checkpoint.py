@@ -45,15 +45,9 @@ from repro.core.split_state import UpperHalf
 class RestoredState:
     step: int
     manifest: Dict[str, Any]
-    # entry -> leaf path -> np.ndarray. Under a streaming restore, cold
-    # entries are LazyLeaves (Mapping) still decoding in the background;
-    # every consumer below this dataclass speaks Mapping, so the
-    # distinction is invisible except to whoever reads `streamer`.
+    # entry -> leaf path -> np.ndarray
     entries: Dict[str, Dict[str, np.ndarray]]
     oplog: OpLog
-    # the StreamingMaterializer that owns in-flight cold entries (None
-    # for an eager restore) — per-source/overlap stats and bulk waits
-    streamer: Any = None
 
 
 class CheckpointManager:
@@ -158,42 +152,23 @@ class CheckpointManager:
 
     def restore(self, step: Optional[int] = None,
                 workers: Optional[int] = None,
-                skip_entries=(), *, streaming: bool = False,
-                lazy_kinds=None,
+                skip_entries=(), *,
                 timings: Optional[Dict[str, Any]] = None) -> RestoredState:
         """Materialize a committed checkpoint's delta chain into host
         arrays. ``workers`` sizes the leaf-decode pool (restore latency
         matters as much as checkpoint overhead — CRIUgpu's point);
         ``skip_entries`` names entries the caller will rebuild instead
-        of rebind, left undecoded.
-
-        ``streaming=True`` returns as soon as the hot tier (op-log,
-        session state, params) is decoded: entries of the cold kinds
-        (``lazy_kinds``, default optimizer moments + KV cache) are
-        ``LazyLeaves`` placeholders that keep fetching/decoding in the
-        background and block their first toucher — bit-identical to the
-        eager path, earlier by the cold tier's fetch+decode time. The
-        full restart lifecycle on top of this is ``core.incarnation``.
-        ``timings`` (eager restore) receives the seconds of blob reads
-        (``fetch_s``) and of decoding (``decode_s``), summed over the
-        decode workers."""
+        of rebind, left undecoded. The full restart lifecycle on top of
+        this is ``core.incarnation``. ``timings`` receives the seconds of
+        blob reads (``fetch_s``) and of decoding (``decode_s``), summed
+        over the decode workers."""
         step = self.resolve_step(step)
-        streamer = None
-        if streaming:
-            from repro.core.streaming import (DEFAULT_LAZY_KINDS,
-                                              materialize_streaming)
-            manifest, entries, streamer = materialize_streaming(
-                self.backend, step, workers=workers,
-                skip_entries=skip_entries,
-                lazy_kinds=(DEFAULT_LAZY_KINDS if lazy_kinds is None
-                            else lazy_kinds))
-        else:
-            manifest, entries = materialize_manifest_chain(
-                self.backend, step, workers=workers,
-                skip_entries=skip_entries, timings=timings)
+        manifest, entries = materialize_manifest_chain(
+            self.backend, step, workers=workers,
+            skip_entries=skip_entries, timings=timings)
         oplog = OpLog.from_json(manifest["oplog"])
         return RestoredState(step=step, manifest=manifest, entries=entries,
-                             oplog=oplog, streamer=streamer)
+                             oplog=oplog)
 
     # retention GC lives in the pipeline (AsyncSnapshotter.gc) and runs
     # on the encode thread after each commit when keep_last is set — do

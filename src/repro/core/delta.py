@@ -653,9 +653,7 @@ def deserialize_tensor(meta: Dict[str, Any],
 
 def leaf_blob_names(meta: Dict[str, Any]) -> List[str]:
     """Every blob hash one leaf's manifest meta references, in decode
-    order (elided zero chunks excluded). The streaming-restore fetch
-    planner sizes its per-leaf dependency counters from this — a leaf
-    becomes decodable the moment the last of exactly these blobs lands."""
+    order (elided zero chunks excluded)."""
     out: List[str] = []
     for pmeta in meta["parts"].values():
         if "dirty" in pmeta:

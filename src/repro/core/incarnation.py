@@ -52,25 +52,17 @@ class Incarnation:
                  step: Optional[int] = None,
                  mesh_factory: Optional[Callable] = None,
                  rewrite_op: Optional[Callable[[Op], Op]] = None,
-                 decode_workers: Optional[int] = None,
-                 skip_entries: Optional[List[str]] = None,
-                 streaming: bool = False,
-                 lazy_kinds=None) -> None:
+                 workers: Optional[int] = None,
+                 skip_entries: Optional[List[str]] = None) -> None:
         self.manager = manager
         self.step = step
         self.mesh_factory = mesh_factory
         self.rewrite_op = rewrite_op
-        self.decode_workers = decode_workers
+        self.workers = workers
         # entries the caller will rebuild rather than rebind (e.g. the
         # KV cache on a re-slot restore) — skipped at decode, so their
         # chains never inflate materialize latency
         self.skip_entries = tuple(skip_entries or ())
-        # streaming: materialize() returns at hot-tier-decoded instead
-        # of everything-decoded; cold entries (lazy_kinds) page in on
-        # first touch while replay/rebind proceed (core.streaming)
-        self.streaming = streaming
-        self.lazy_kinds = lazy_kinds
-        self.streamer = None
         self.restored: Optional[RestoredState] = None
         self.lower: Optional[LowerHalf] = None
         self.released = False
@@ -90,30 +82,17 @@ class Incarnation:
         arrays + the pruned op-log — everything restore needs, on any
         topology.
 
-        With ``streaming=True`` this returns once the *hot* tier is
-        decoded (``materialize_s`` then measures time-to-hot, the
-        latency the resumed app actually waits); the cold tier keeps
-        streaming behind replay and rebind, and ``stream_timings()``
-        reports the per-phase fetch/decode/fault counters. An eager
-        restore splits ``materialize_s`` into ``fetch_s`` (blob reads)
-        and ``decode_s`` (zlib, XOR, sparse patch), each summed over the
+        ``materialize_s`` splits into ``fetch_s`` (blob reads) and
+        ``decode_s`` (zlib, XOR, sparse patch), each summed over the
         decode workers."""
         if self.restored is not None:
             raise LifecycleError("materialize() already ran")
-        kw: Dict[str, Any] = {}
-        if self.streaming:
-            kw["streaming"] = True
-            if self.lazy_kinds is not None:
-                kw["lazy_kinds"] = self.lazy_kinds
-        else:
-            self.timings.update(fetch_s=0.0, decode_s=0.0)
-            kw["timings"] = self.timings
+        self.timings.update(fetch_s=0.0, decode_s=0.0)
         with span("ckpt.restore.materialize", into=self.timings,
                   key="materialize_s"):
             self.restored = self.manager.restore(
-                self.step, workers=self.decode_workers,
-                skip_entries=self.skip_entries, **kw)
-        self.streamer = self.restored.streamer
+                self.step, workers=self.workers,
+                skip_entries=self.skip_entries, timings=self.timings)
         self.step = self.restored.step
         return self.restored
 
@@ -193,19 +172,6 @@ class Incarnation:
         if self.restored is not None:
             self.restored.entries = {}
         self.released = True
-
-    def stream_timings(self) -> Optional[Dict[str, Any]]:
-        """Per-phase streaming-restore counters (fetch bytes/s per
-        source, decode overlap %, lazy faults served, hedges won), or
-        None on an eager restore. Safe to call at any point after
-        materialize(); counters reflect progress so far, and the
-        snapshot is also folded into ``timings['stream']`` so a later
-        reader of the plain timings dict sees it."""
-        if self.streamer is None:
-            return None
-        t = self.streamer.timings()
-        self.timings["stream"] = t
-        return t
 
     def has_entry(self, name: str) -> bool:
         if self.restored is None:

@@ -23,11 +23,11 @@ The mechanism is deliberately the C/R protocol, not object handoff:
             store under ``<store>/_moves/...`` with ``chain=1``, so
             migration traffic can never interleave with (or corrupt)
             the source engine's periodic delta chain;
-  restore   the bundle restores (streaming by default) on the target
-            side and every session re-enters through admission, which
-            replays prompt + generated-so-far into its new slot — the
-            PR 2 re-slot machinery, so an N-slot engine's sessions
-            land on an M-slot engine token-identically;
+  restore   the bundle restores on the target side and every
+            session re-enters through admission, which replays prompt +
+            generated-so-far into its new slot — the re-slot
+            machinery, so an N-slot engine's sessions land on an
+            M-slot engine token-identically;
   cutover   requests that arrived mid-move for the draining engine
             were held by the router and are replayed on the target.
 
@@ -47,9 +47,6 @@ import numpy as np
 
 from repro.api.errors import MigrationError
 
-# entry kinds follow the serving engine's vocabulary: request trees are
-# scheduler state (hot tier under a streaming restore — a move wants
-# the sessions first, always)
 _BUNDLE_KIND = "serving-move"
 
 
@@ -166,7 +163,6 @@ def migrate_sessions(source: Any, target: Any, *, via: str,
                      include_queue: bool = False,
                      batch: Optional[int] = None,
                      deadline_s: Optional[float] = None,
-                     streaming: bool = True,
                      move_id: int = 0,
                      source_name: str = "source",
                      target_name: str = "target",
@@ -176,11 +172,11 @@ def migrate_sessions(source: Any, target: Any, *, via: str,
 
     Each batch freezes at most ``batch`` slots (None = all chosen slots
     at once), snapshots them as a ``SessionBundle`` on a fresh move
-    channel under ``via``, restores the bundle (``streaming`` by
-    default) and re-admits every session on the target; ``settle`` runs
-    one target engine step so the batch's blackout clock stops at
-    "serving again", not "queued". The source keeps decoding its
-    remaining slots between batches. ``deadline_s`` is judged against
+    channel under ``via``, restores the bundle and re-admits every
+    session on the target; ``settle`` runs one target engine step so
+    the batch's blackout clock stops at "serving again", not "queued".
+    The source keeps decoding its remaining slots between batches.
+    ``deadline_s`` is judged against
     the worst per-batch blackout and reported on the result — a planned
     move that missed its drain deadline must be visible, not silent."""
     from repro.api.policy import Policy
@@ -219,8 +215,7 @@ def migrate_sessions(source: Any, target: Any, *, via: str,
             chan.attach(SessionBundle(reqs, source.steps))
             chan.snapshot(block=True)
             t1 = time.monotonic()
-            landed = chan.restore("latest", expect_kind=_BUNDLE_KIND,
-                                  streaming=streaming)
+            landed = chan.restore("latest", expect_kind=_BUNDLE_KIND)
         t2 = time.monotonic()
         for r in landed.requests:
             target.submit(r)
@@ -336,7 +331,6 @@ class FleetRouter:
                 include_queue: bool = False,
                 batch: Optional[int] = None,
                 deadline_s: Optional[float] = None,
-                streaming: bool = True,
                 keep_draining: bool = False) -> MoveResult:
         """Move ``slots`` (default: every live session) from engine
         ``src`` to ``dst``. The source serves its unaffected slots
@@ -358,7 +352,7 @@ class FleetRouter:
                 batch=batch if batch is not None else self.migrate_batch,
                 deadline_s=deadline_s if deadline_s is not None
                 else self.drain_deadline_s,
-                streaming=streaming, move_id=move_id,
+                move_id=move_id,
                 source_name=src, target_name=dst)
             # the landed request objects are the live ones now — the
             # router must watch them, not the frozen source-side twins

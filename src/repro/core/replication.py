@@ -23,7 +23,7 @@ from __future__ import annotations
 
 from dataclasses import asdict, dataclass, field
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 from repro.core.backends.base import write_atomic
 from repro.core.backends.sharded import ShardedBackend
@@ -143,42 +143,6 @@ def repair(backend: ShardedBackend, host: Optional[int] = None,
                 write_atomic(p, data, backend.fsync)
                 rep.restored += 1
     return rep
-
-
-def blob_sources(backend, name: str) -> List[Tuple[str, Callable[[], bytes]]]:
-    """Every place one blob can be read from, as ordered
-    ``(label, read_callable)`` pairs — the preferred source first.
-
-    This is the streaming restore's fetch fan-out: a ``ShardedBackend``
-    exposes the primary copy on the owner host and the ``replica_`` copy
-    on its (h+1)%N ring successor as *independent* sources, so the
-    fetcher can hedge a slow or dead primary with its peer instead of
-    serializing behind ``get_blob``'s internal failover. Backends with
-    their own tiering (e.g. the ``cached:`` read-through store) override
-    the enumeration via a ``blob_sources`` method; anything else is a
-    single opaque source. Each callable raises (``FileNotFoundError``,
-    ``IOError``) when its copy is unavailable *at read time* — liveness
-    is judged per read, not per plan."""
-    own = getattr(backend, "blob_sources", None)
-    if callable(own):
-        return own(name)
-    if isinstance(backend, ShardedBackend):
-        out: List[Tuple[str, Callable[[], bytes]]] = []
-        for host, path in backend._placements(name):
-            if host in backend._failed_hosts:
-                continue
-
-            def read(p=path, h=host) -> bytes:
-                if h in backend._failed_hosts:
-                    raise IOError(f"host {h} down; read of {p.name} lost")
-                return p.read_bytes()
-
-            out.append((f"host_{host:03d}", read))
-        if out:
-            return out
-        # every placement's host is failed: fall through to get_blob so
-        # the error message names each dead copy
-    return [("store", lambda: backend.get_blob(name))]
 
 
 def verify_restorable(backend: ShardedBackend, manifest: dict,

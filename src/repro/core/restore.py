@@ -70,14 +70,7 @@ def materialize_entry(
     template: abstract pytree (ShapeDtypeStructs or arrays) giving
     structure + dtypes; logical_template: matching pytree of logical axis
     tuples (None leaves -> replicated)."""
-    by_path = restored.entries[name]
-    # a streaming restore hands us a LazyLeaves Mapping: binding a whole
-    # entry is a bulk page-in, so wait for it as one promoted batch
-    # instead of faulting leaf-by-leaf through fill_like
-    waiter = getattr(by_path, "wait", None)
-    if callable(waiter):
-        waiter()
-    host_tree = fill_like(template, by_path)
+    host_tree = fill_like(template, restored.entries[name])
 
     if mesh is None:
         return jax.tree.map(

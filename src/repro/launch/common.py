@@ -70,12 +70,6 @@ def add_store_args(ap: argparse.ArgumentParser, *,
                     help="resume from a checkpoint: 'latest' (the bare "
                          "flag) or a step number; fails instead of "
                          "cold-starting when none is restorable")
-    ap.add_argument("--streaming-restore", action="store_true",
-                    help="stream the --resume: come back up as soon as "
-                         "the hot tier (sessions, params) is decoded; "
-                         "cold entries (optimizer moments, KV cache) "
-                         "page in on first touch. Bit-identical to the "
-                         "eager restore")
 
 
 def resolve_store(args, prog: str) -> Tuple[Optional[str], Optional[str]]:
@@ -110,28 +104,12 @@ def build_session(spec: str, prog: str, *, interval: Optional[int] = None,
 
 
 def restore_timings_line(inc) -> str:
-    """The per-phase restore observability for a RESUMED banner: eager
-    phase timings always; under a streaming restore, also the pipeline
-    counters — fetch wall + per-source throughput, how much decode hid
-    inside the fetch window, lazy faults served, hedges won."""
+    """The per-phase restore timings for a RESUMED banner."""
     t = inc.timings
     parts = [f"materialize {t.get('materialize_s', 0.0):.2f}s",
              f"replay {t.get('replay_s', 0.0):.2f}s"]
     if "rebind_s" in t:
         parts.append(f"rebind {t['rebind_s']:.2f}s")
-    st = inc.stream_timings() if hasattr(inc, "stream_timings") else None
-    if st is not None:
-        rates = ", ".join(
-            f"{k} {v:.1f}MB/s" for k, v in
-            sorted(st.get("fetch_mb_s_per_source", {}).items()))
-        stream = f"stream[fetch {st['fetch_s']:.2f}s"
-        if rates:
-            stream += f" ({rates})"
-        stream += (f", decode overlap {st['decode_overlap_pct']:.0f}%, "
-                   f"lazy faults {st['lazy_faults']}")
-        if st.get("hedges"):
-            stream += f", hedges won {st['hedge_wins']}/{st['hedges']}"
-        parts.append(stream + "]")
     return ", ".join(parts)
 
 

@@ -144,8 +144,7 @@ def main(argv=None) -> int:
 
     if resume:
         eng = sess.restore(step=step, expect_kind="serving",
-                           params=params, n_slots=args.slots,
-                           streaming=args.streaming_restore or None)
+                           params=params, n_slots=args.slots)
         reqs = eng.live_requests()
         inc = eng.incarnation
         print(f"[serve] RESUMED at engine step {eng.steps} with "

@@ -104,8 +104,7 @@ def main(argv=None) -> int:
             return 2
 
     if sess.latest_step() is not None:
-        tr = sess.restore(step=resume_step, expect_kind="train",
-                          streaming=args.streaming_restore or None)
+        tr = sess.restore(step=resume_step, expect_kind="train")
         inc = tr.incarnation
         print(f"[launch] RESUMED {args.arch} at step "
               f"{tr.checkpoint_step()} from {spec} "

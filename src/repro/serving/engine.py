@@ -249,11 +249,6 @@ class ServingEngine:
         # bucket, and a batch-1 decode for recurrent-state replay
         self._admit_prefill: Dict[int, Any] = {}
         self._slot_decode = None
-        # streaming restore: the checkpointed KV cache still decoding in
-        # the background (LazyLeaves), and the slots admission filled
-        # while it was in flight — see _ensure_cache()
-        self._pending_cache = None
-        self._touched_slots: set = set()
         self._prefill_admission = (cfg.family not in ("ssm", "hybrid")
                                    and not cfg.is_encoder_decoder)
         # optional live-session checkpointing (core.async_snapshot):
@@ -291,7 +286,6 @@ class ServingEngine:
         contents, slot bookkeeping (positions + pending tokens), every
         in-flight request (prompt, generated tokens, budget, identity)
         and the waiting queue. Params are the trainer's job, not ours."""
-        self._ensure_cache()   # never snapshot a half-paged-in cache
         up = UpperHalf()
         up.register("kv_cache", "cache", self.cache)
         up.register("sessions", "sessions", {
@@ -362,7 +356,7 @@ class ServingEngine:
             stacklevel=2)
         return CheckpointSession.from_manager(manager).restore(
             step=step, expect_kind="serving", mesh_factory=mesh_factory,
-            decode_workers=decode_workers, params=params,
+            workers=decode_workers, params=params,
             n_slots=n_slots, mesh=mesh)
 
     def bind(self, restore: RestoreContext) -> None:
@@ -386,22 +380,10 @@ class ServingEngine:
                       for _, v in sorted(sched.get("queue", {}).items())]
 
         if not reslot:
-            kv = inc.entry_paths("kv_cache")
-            if callable(getattr(kv, "wait", None)):
-                # streaming restore: the KV cache is the cold tier.
-                # Keep serving on the fresh cache — admission can
-                # prefill new requests into free slots while the
-                # checkpointed contents stream in — and land the
-                # restored bytes just before the next full-batch
-                # decode (_ensure_cache), which is the first moment
-                # anything reads other slots' columns.
-                self._pending_cache = kv
-                self._touched_slots = set()
-            else:
-                host = fill_like(self.cache, kv)
-                self.cache = jax.tree.map(
-                    lambda t, v: jnp.asarray(np.asarray(v), dtype=t.dtype),
-                    self.cache, host)
+            host = fill_like(self.cache, inc.entry_paths("kv_cache"))
+            self.cache = jax.tree.map(
+                lambda t, v: jnp.asarray(np.asarray(v), dtype=t.dtype),
+                self.cache, host)
             sess = tree_from_paths(inc.entry_paths("sessions"))
             self.slot_pos = np.asarray(sess["slot_pos"], np.int32).copy()
             self.slot_tok = np.asarray(
@@ -481,11 +463,6 @@ class ServingEngine:
         self.slot_req[s] = req
         self.slot_tok[s, 0] = int(seq[-1])
         self.slot_pos[s] = len(seq) - 1
-        if self._pending_cache is not None:
-            # admitted while the checkpointed cache is still streaming:
-            # this slot's column now holds fresh prefill state that the
-            # deferred merge must not overwrite
-            self._touched_slots.add(s)
 
     def _prefill_slot(self, s: int, hist: np.ndarray) -> None:
         """One batched prefill call instead of O(len) full-slot decodes:
@@ -541,35 +518,6 @@ class ServingEngine:
             return full.at[s:s + 1].set(sl)
         self.cache = jax.tree.map(merge, self.cache, one)
 
-    def _ensure_cache(self) -> None:
-        """Land the streamed KV cache (first-touch page-in of the cold
-        tier). Admission runs *before* this in ``step()`` on purpose:
-        prefill compiles and runs while the restored cache is still
-        fetching/decoding in the background, which is where streaming
-        restore buys its time-to-first-admission. Slot columns admission
-        already rewrote keep their fresh prefill state; every other
-        column takes the restored bytes — exactly the state the eager
-        path reaches by restoring first and letting admission overwrite,
-        so the two paths stay bit-identical."""
-        if self._pending_cache is None:
-            return
-        pending, self._pending_cache = self._pending_cache, None
-        pending.wait()
-        host = fill_like(self.cache, pending)
-        touched = sorted(self._touched_slots)
-        self._touched_slots = set()
-
-        def land(cur, v):
-            cur = jnp.asarray(cur)
-            rest = jnp.asarray(np.asarray(v), cur.dtype)
-            for s in touched:
-                if rest.ndim >= 2:
-                    rest = rest.at[:, s:s + 1].set(cur[:, s:s + 1])
-                else:
-                    rest = rest.at[s:s + 1].set(cur[s:s + 1])
-            return rest
-        self.cache = jax.tree.map(land, self.cache, host)
-
     def step(self) -> int:
         """One engine iteration; returns #active slots."""
         with span("serve.step"):
@@ -577,7 +525,6 @@ class ServingEngine:
 
     def _step(self) -> int:
         self._admit()
-        self._ensure_cache()
         active = [s for s in range(self.n_slots) if self.slot_req[s]]
         if not active:
             return 0

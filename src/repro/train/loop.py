@@ -257,7 +257,7 @@ class Trainer:
             stacklevel=2)
         return CheckpointSession.from_manager(manager).restore(
             step=step, expect_kind="train", mesh_factory=mesh_factory,
-            rewrite_op=rewrite_op, decode_workers=decode_workers)
+            rewrite_op=rewrite_op, workers=decode_workers)
 
     # --- observability ---------------------------------------------------------
 

@@ -127,34 +127,18 @@ def run_kill(spec: FamilySpec, store: str) -> str:
             f"{spec.family}: restored at step {at}, wanted {boundary}"
         dr.advance(app2, dr.total - at)
         got = dr.digest(app2)
-
-        # same cell, streaming schedule: restore the same step with the
-        # pipelined materializer (hot tier eager, cold leaves paged in
-        # on first touch) and continue — streaming is a schedule, not a
-        # different restore, so the digest must not move
-        app3 = sess.restore("latest", streaming=True,
-                            **dr.restore_kwargs())
-        at3 = dr.step_of(app3)
-        assert at3 == boundary, \
-            f"{spec.family}: streaming restored at step {at3}, " \
-            f"wanted {boundary}"
-        dr.advance(app3, dr.total - at3)
-        got_streamed = dr.digest(app3)
     assert got == want, \
         f"{spec.family}: post-restore digest {got} != reference {want}"
-    assert got_streamed == want, \
-        f"{spec.family}: streaming restore digest {got_streamed} != " \
-        f"reference {want}"
     _KILL[(spec.family, store.split(":", 1)[0])] = got
     return got
 
 
 def run_degraded(spec: FamilySpec, store: str) -> str:
     """One dead peer per shard ring: the replicated package loses a
-    host wholesale after the checkpoint commits. The streaming restore
-    must route its fetches through the surviving copies — same digest
-    as the reference run AND as the degraded eager restore (fallback is
-    a routing decision, never a correctness relaxation)."""
+    host wholesale after the checkpoint commits. The restore must read
+    each blob from its surviving copy — same digest as the reference
+    run (fallback is a routing decision, never a correctness
+    relaxation)."""
     dr = spec.train
     want = reference_digest(spec)
     policy = Policy(interval=dr.interval, chain=3, keep_last=4)
@@ -169,23 +153,14 @@ def run_degraded(spec: FamilySpec, store: str) -> str:
         del app                               # hard kill
         sess.backend.fail_host(1)             # ... and a dead peer
 
-        app2 = sess.restore("latest", streaming=True,
-                            **dr.restore_kwargs())
+        app2 = sess.restore("latest", **dr.restore_kwargs())
         at = dr.step_of(app2)
         assert at == boundary, \
-            f"{spec.family}: degraded streaming restored at {at}, " \
-            f"wanted {boundary}"
+            f"{spec.family}: degraded restore at {at}, wanted {boundary}"
         dr.advance(app2, dr.total - at)
         got = dr.digest(app2)
-
-        app3 = sess.restore("latest", **dr.restore_kwargs())
-        dr.advance(app3, dr.total - boundary)
-        got_eager = dr.digest(app3)
     assert got == want, \
-        f"{spec.family}: degraded streaming digest {got} != " \
-        f"reference {want}"
-    assert got_eager == got, \
-        f"{spec.family}: degraded eager {got_eager} != streaming {got}"
+        f"{spec.family}: degraded digest {got} != reference {want}"
     return got
 
 
@@ -376,8 +351,8 @@ def _restore_growing(restore) -> _GrowingApp:
 
 def run_midchain(store: str) -> None:
     """An entry first introduced mid-chain — its first appearance is a
-    non-base delta link — must checkpoint and restore bit-identically,
-    eager AND streaming, through the public API alone."""
+    non-base delta link — must checkpoint and restore bit-identically
+    through the public API alone."""
     ref = _GrowingApp()
     ref.advance(6)
     want = ref.digest()
@@ -392,16 +367,12 @@ def run_midchain(store: str) -> None:
             "the late entry must exist before the kill for the cell " \
             "to exercise a mid-chain introduction"
         del app                                   # hard kill
-        for streaming in (False, True):
-            app2 = sess.restore("latest", streaming=streaming)
-            assert app2.step == 4, \
-                f"restored at step {app2.step}, wanted 4"
-            app2.advance(2)
-            got = app2.digest()
-            assert got == want, (
-                "mid-chain-new-entry: "
-                f"{'streaming' if streaming else 'eager'} digest {got} "
-                f"!= reference {want}")
+        app2 = sess.restore("latest")
+        assert app2.step == 4, f"restored at step {app2.step}, wanted 4"
+        app2.advance(2)
+        got = app2.digest()
+    assert got == want, \
+        f"mid-chain-new-entry: digest {got} != reference {want}"
 
 
 def tear_last_commit(store: str) -> int:

@@ -37,16 +37,14 @@ def _backend_for(mode: str, backend: str) -> str:
 
 def fast_cells() -> List[Cell]:
     # one targeted degradation cell rides in the fast tier: the
-    # replicated sharded package loses a peer and the streaming restore
-    # must route around it. Degradation is a store property, not a
-    # family property, so the full family×degraded product would be
-    # redundant — one family stands in for all of them.
-    # two more targeted cells ride in the fast tier: degradation is a
-    # store property (one family stands in for all), and the mid-chain
+    # replicated sharded package loses a peer and the restore must read
+    # around it. Degradation is a store property, not a family
+    # property, so the full family×degraded product would be
+    # redundant — one family stands in for all of them. The mid-chain
     # new-entry cell is a chain-shape property — an app whose semantic
     # state grows mid-run, so an entry's first appearance is a non-base
-    # delta link that both restore schedules must handle
-    # and one churn-grow cell: shrink-then-grow through the supervisor
+    # delta link that the restore must handle. And one churn-grow cell:
+    # shrink-then-grow through the supervisor
     # is a *sequencing* property of the restore primitive (elastic
     # re-shard both directions), not a family property — one family
     # stands in for all of them

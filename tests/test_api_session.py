@@ -198,7 +198,7 @@ def test_trainer_restore_shim_delegates(monkeypatch):
     assert out == "the-trainer"
     assert calls["step"] == 7
     assert calls["expect_kind"] == "train"
-    assert calls["decode_workers"] == 2
+    assert calls["workers"] == 2
 
 
 def test_engine_restore_shim_delegates(monkeypatch):

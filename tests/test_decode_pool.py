@@ -348,7 +348,7 @@ def test_restore_timings_count_work_not_the_wait(tmp_path):
 
     mgr.backend.get_blob = slow
     try:
-        inc = Incarnation(mgr, step=1, decode_workers=8)
+        inc = Incarnation(mgr, step=1, workers=8)
         got = inc.materialize().entries["params"]["['w']"]
         np.testing.assert_array_equal(got, w)
         t = inc.timings
@@ -357,7 +357,7 @@ def test_restore_timings_count_work_not_the_wait(tmp_path):
         assert t["fetch_s"] >= n_chunks * 0.5 > t["materialize_s"]
         assert 0 <= t["decode_s"] < t["decode_pool_wait_s"]
 
-        inc1 = Incarnation(mgr, step=1, decode_workers=1)
+        inc1 = Incarnation(mgr, step=1, workers=1)
         np.testing.assert_array_equal(
             inc1.materialize().entries["params"]["['w']"], w)
         t1 = inc1.timings
